@@ -21,6 +21,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.launch.compile_cache import enable_compile_cache
+
 
 def _timeit(fn, n=100, warmup=3):
     for _ in range(warmup):
@@ -1293,6 +1295,7 @@ def check_against(rows: list, baseline_paths: list, tolerance: float) -> list:
 
 
 def main() -> None:
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--quick", action="store_true")
     ap.add_argument("--smoke", action="store_true",

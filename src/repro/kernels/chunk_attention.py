@@ -23,14 +23,19 @@ from the length-aware prefill kernel:
 * **Grid (B, H, nk)**: the whole chunk is one Q tile (C is small — 16..128);
   the KV axis is innermost/sequential so the online-softmax state (m, l,
   acc) lives in VMEM scratch across the cache sweep.
+* **Head-major tiles**: the wrapper moves heads ahead of the sequence axis
+  ((B, L, KVH, hd) -> (B, KVH, L, hd)) and gives ``slot_pos`` a unit axis
+  ((B, 1, L)), so every tile's last two dims are (rows, hd) or (1, block_l)
+  — the layouts Mosaic can tile.
 
 Query rows at or beyond ``valid`` are zeroed in the output (they are
 padding; the engine discards them). The pure-jnp oracle is
 ``repro.kernels.ref.chunk_attention_ref``.
 
 Quantized K/V (DESIGN.md §14): ``k_scale``/``v_scale`` (B, L, KVH) f32
-stream as their own (1, block_l, 1) tiles and each K/V tile is dequantized
-in-kernel right after its DMA — same f32-multiply-then-cast as
+stream as their own (1, 1, block_l, 1) tiles of the head-major
+(B, KVH, L, 1) scales, and each K/V tile is dequantized in-kernel right
+after its DMA — same f32-multiply-then-cast as
 ``repro.kernels.quant.dequantize_kv``, so XLA fallback and kernel agree.
 """
 from __future__ import annotations
@@ -69,15 +74,13 @@ def _chunk_kernel(pos0_ref, valid_ref, q_ref, k_ref, v_ref, sp_ref, *rest,
 
     @pl.when(live)
     def _compute():
-        q = q_ref[0, :, 0, :]                        # (C, hd)
-        k = k_ref[0, :, 0, :]                        # (bl, hd)
-        v = v_ref[0, :, 0, :]
+        q = q_ref[0, 0]                              # (C, hd)
+        k = k_ref[0, 0]                              # (bl, hd)
+        v = v_ref[0, 0]
         if quantized:
-            k = (k.astype(jnp.float32)
-                 * ks_ref[0, :, 0][:, None]).astype(q.dtype)
-            v = (v.astype(jnp.float32)
-                 * vs_ref[0, :, 0][:, None]).astype(q.dtype)
-        sp = sp_ref[0, :]                            # (bl,) slot_pos
+            k = (k.astype(jnp.float32) * ks_ref[0, 0]).astype(q.dtype)
+            v = (v.astype(jnp.float32) * vs_ref[0, 0]).astype(q.dtype)
+        sp = sp_ref[0, 0]                            # (bl,) slot_pos
         s = jax.lax.dot_general(
             q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
         ) * scale                                    # (C, bl)
@@ -101,7 +104,7 @@ def _chunk_kernel(pos0_ref, valid_ref, q_ref, k_ref, v_ref, sp_ref, *rest,
         l = jnp.maximum(l_ref[...], 1e-30)
         rows = jax.lax.broadcasted_iota(jnp.int32, (C, 1), 0)
         out = jnp.where(rows < nv, acc_ref[...] / l, 0.0)
-        o_ref[0, :, 0, :] = out.astype(o_ref.dtype)
+        o_ref[0, 0] = out.astype(o_ref.dtype)
 
 
 def chunk_attention(
@@ -131,39 +134,38 @@ def chunk_attention(
         _chunk_kernel, scale=scale, C=C, block_l=block_l, nk=nk,
         quantized=quantized,
     )
+    q_spec = pl.BlockSpec((1, 1, C, hd), lambda b, h, ki, p0, nv: (b, h, 0, 0))
+    kv_spec = pl.BlockSpec((1, 1, block_l, hd),
+                           lambda b, h, ki, p0, nv: (b, h // G, ki, 0))
     in_specs = [
-        pl.BlockSpec((1, C, 1, hd), lambda b, h, ki, p0, nv: (b, 0, h, 0)),
-        pl.BlockSpec((1, block_l, 1, hd),
-                     lambda b, h, ki, p0, nv: (b, ki, h // G, 0)),
-        pl.BlockSpec((1, block_l, 1, hd),
-                     lambda b, h, ki, p0, nv: (b, ki, h // G, 0)),
-        pl.BlockSpec((1, block_l), lambda b, h, ki, p0, nv: (b, ki)),
+        q_spec, kv_spec, kv_spec,
+        pl.BlockSpec((1, 1, block_l), lambda b, h, ki, p0, nv: (b, 0, ki)),
     ]
-    operands = [pos0.astype(jnp.int32), valid.astype(jnp.int32), q, k, v,
-                slot_pos]
+    # head-major operands (see module docstring)
+    operands = [pos0.astype(jnp.int32), valid.astype(jnp.int32),
+                q.transpose(0, 2, 1, 3), k.transpose(0, 2, 1, 3),
+                v.transpose(0, 2, 1, 3), slot_pos[:, None, :]]
     if quantized:
-        in_specs += [
-            pl.BlockSpec((1, block_l, 1),
-                         lambda b, h, ki, p0, nv: (b, ki, h // G)),
-            pl.BlockSpec((1, block_l, 1),
-                         lambda b, h, ki, p0, nv: (b, ki, h // G)),
-        ]
-        operands += [k_scale, v_scale]
+        s_spec = pl.BlockSpec((1, 1, block_l, 1),
+                              lambda b, h, ki, p0, nv: (b, h // G, ki, 0))
+        in_specs += [s_spec, s_spec]
+        operands += [s.transpose(0, 2, 1)[..., None]   # (B, KVH, L, 1)
+                     for s in (k_scale, v_scale)]
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
         grid=(B, H, nk),
         in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, C, 1, hd),
-                               lambda b, h, ki, p0, nv: (b, 0, h, 0)),
+        out_specs=q_spec,
         scratch_shapes=[
             pltpu.VMEM((C, 1), jnp.float32),     # m
             pltpu.VMEM((C, 1), jnp.float32),     # l
             pltpu.VMEM((C, hd), jnp.float32),    # acc
         ],
     )
-    return pl.pallas_call(
+    out = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((B, C, H, hd), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((B, H, C, hd), q.dtype),
         interpret=interpret,
     )(*operands)
+    return out.transpose(0, 2, 1, 3)

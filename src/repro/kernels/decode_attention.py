@@ -13,9 +13,11 @@ small matmuls ride along. Two design points make it TPU-native:
   persists in VMEM scratch across cache tiles of one (batch, kv-head).
 
 Slot validity (ring buffer: absolute position in slot_pos, -1 = empty,
-optional sliding window) is evaluated per tile. Tiles: k/v (1, block_l, 1,
-hd) VMEM; slot_pos (1, block_l); q (1, 1, G, hd); out written at the last
-cache tile.
+optional sliding window) is evaluated per tile. The wrapper makes the cache
+head-major ((B, L, KVH, hd) -> (B, KVH, L, hd)) and gives ``slot_pos`` a
+unit axis, so every tile's last two dims are ones Mosaic can tile. Tiles:
+k/v (1, 1, block_l, hd) VMEM; slot_pos (1, 1, block_l); q (1, 1, G, hd);
+``pos`` is scalar-prefetched; out written at the last cache tile.
 """
 from __future__ import annotations
 
@@ -29,8 +31,9 @@ from jax.experimental.pallas import tpu as pltpu
 NEG_INF = -1e30
 
 
-def _decode_kernel(q_ref, k_ref, v_ref, sp_ref, pos_ref, o_ref,
+def _decode_kernel(pos_ref, q_ref, k_ref, v_ref, sp_ref, o_ref,
                    m_ref, l_ref, acc_ref, *, scale, block_l, nl, window):
+    b = pl.program_id(0)
     li = pl.program_id(2)
 
     @pl.when(li == 0)
@@ -40,10 +43,10 @@ def _decode_kernel(q_ref, k_ref, v_ref, sp_ref, pos_ref, o_ref,
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
     q = q_ref[0, 0, :, :]                               # (G, hd)
-    k = k_ref[0, :, 0, :]                               # (bl, hd)
-    v = v_ref[0, :, 0, :]
-    sp = sp_ref[0, :]                                   # (bl,) int32 abs pos
-    pos = pos_ref[0]                                    # scalar int32
+    k = k_ref[0, 0]                                     # (bl, hd)
+    v = v_ref[0, 0]
+    sp = sp_ref[0, 0]                                   # (bl,) int32 abs pos
+    pos = pos_ref[b]                                    # scalar int32
 
     s = jax.lax.dot_general(
         q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
@@ -93,23 +96,28 @@ def decode_attention(
     kernel = functools.partial(
         _decode_kernel, scale=scale, block_l=block_l, nl=nl, window=window
     )
-    out = pl.pallas_call(
-        kernel,
+    q_spec = pl.BlockSpec((1, 1, G, hd), lambda b, kv, li, pos: (b, kv, 0, 0))
+    kv_spec = pl.BlockSpec((1, 1, block_l, hd),
+                           lambda b, kv, li, pos: (b, kv, li, 0))
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=1,
         grid=(B, KVH, nl),
         in_specs=[
-            pl.BlockSpec((1, 1, G, hd), lambda b, kv, li: (b, kv, 0, 0)),
-            pl.BlockSpec((1, block_l, 1, hd), lambda b, kv, li: (b, li, kv, 0)),
-            pl.BlockSpec((1, block_l, 1, hd), lambda b, kv, li: (b, li, kv, 0)),
-            pl.BlockSpec((1, block_l), lambda b, kv, li: (b, li)),
-            pl.BlockSpec((1,), lambda b, kv, li: (b,)),
+            q_spec, kv_spec, kv_spec,
+            pl.BlockSpec((1, 1, block_l), lambda b, kv, li, pos: (b, 0, li)),
         ],
-        out_specs=pl.BlockSpec((1, 1, G, hd), lambda b, kv, li: (b, kv, 0, 0)),
-        out_shape=jax.ShapeDtypeStruct((B, KVH, G, hd), q.dtype),
+        out_specs=q_spec,
         scratch_shapes=[
             pltpu.VMEM((G, 1), jnp.float32),
             pltpu.VMEM((G, 1), jnp.float32),
             pltpu.VMEM((G, hd), jnp.float32),
         ],
+    )
+    out = pl.pallas_call(
+        kernel,
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((B, KVH, G, hd), q.dtype),
         interpret=interpret,
-    )(qg, k, v, slot_pos, pos)
+    )(pos.astype(jnp.int32), qg, k.transpose(0, 2, 1, 3),
+      v.transpose(0, 2, 1, 3), slot_pos[:, None, :])
     return out.reshape(B, H, hd)

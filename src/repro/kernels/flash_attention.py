@@ -2,11 +2,14 @@
 
 Grid: (B, H, nq, nk) — nk is the innermost (sequential on TPU) axis so the
 online-softmax running state (m, l, acc) lives in VMEM scratch across the
-KV sweep of one Q tile. Tiles:
+KV sweep of one Q tile. The wrapper moves heads ahead of the sequence axis
+((B, S, H, hd) -> (B, H, S, hd)) so every tile's last two dims are
+(block, hd): Mosaic tiles the last two dims of a block and refuses a
+one-head (block, 1, hd) slice of a (S, H, hd) array. Tiles:
 
-  q     (1, block_q, 1, hd)   VMEM    — one (batch, head) Q tile
-  k/v   (1, block_k, 1, hd)   VMEM    — KV head = q_head // G (GQA folding)
-  out   (1, block_q, 1, hd)   VMEM    — written on the last KV step
+  q     (1, 1, block_q, hd)   VMEM    — one (batch, head) Q tile
+  k/v   (1, 1, block_k, hd)   VMEM    — KV head = q_head // G (GQA folding)
+  out   (1, 1, block_q, hd)   VMEM    — written on the last KV step
 
 block_q/block_k default 512/512: with hd=128 and f32 scratch the working set
 is ~ (512*128)*3 (q,k,v) * 2B + (512*128 + 2*512)*4B acc ≈ 0.7 MB — well
@@ -29,7 +32,8 @@ Numerics: scores/softmax in f32 (preferred_element_type), inputs bf16/f32.
 Quantized K/V (DESIGN.md §14): pass ``k_scale``/``v_scale`` (B, Sk, KVH)
 f32 alongside int8/fp8 ``k``/``v`` and the kernel dequantizes each tile
 *after* the HBM->VMEM DMA — the bandwidth win is the point; scale tiles ride
-their own (1, block_k, 1) BlockSpecs. Dequant matches
+their own (1, 1, block_k, 1) BlockSpecs over the head-major (B, KVH, Sk, 1)
+scales. Dequant matches
 ``repro.kernels.quant.dequantize_kv`` exactly (f32 multiply, cast to the
 query dtype) so the XLA fallback and the kernel agree bit-for-float. Note
 the TPU int8 minimum tile is (32, 128): block_k stays >= 32 on hardware;
@@ -50,8 +54,8 @@ NEG_INF = -1e30
 
 def _dequant_tile(x, s_ref, dtype):
     """Per-token-per-head dequant of one (bk, hd) K/V tile; ``s_ref`` holds
-    the tile's (1, bk, 1) scale block."""
-    return (x.astype(jnp.float32) * s_ref[0, :, 0][:, None]).astype(dtype)
+    the tile's (1, 1, bk, 1) scale block."""
+    return (x.astype(jnp.float32) * s_ref[0, 0]).astype(dtype)
 
 
 def _attn_kernel(q_ref, k_ref, v_ref, *rest, scale, block_q, block_k, nk,
@@ -81,9 +85,9 @@ def _attn_kernel(q_ref, k_ref, v_ref, *rest, scale, block_q, block_k, nk,
 
     @pl.when(live)
     def _compute():
-        q = q_ref[0, :, 0, :]                       # (bq, hd)
-        k = k_ref[0, :, 0, :]                       # (bk, hd)
-        v = v_ref[0, :, 0, :]
+        q = q_ref[0, 0]                             # (bq, hd)
+        k = k_ref[0, 0]                             # (bk, hd)
+        v = v_ref[0, 0]
         if quantized:
             k = _dequant_tile(k, ks_ref, q.dtype)
             v = _dequant_tile(v, vs_ref, q.dtype)
@@ -112,7 +116,7 @@ def _attn_kernel(q_ref, k_ref, v_ref, *rest, scale, block_q, block_k, nk,
     @pl.when(ki == nk - 1)
     def _finalize():
         l = jnp.maximum(l_ref[...], 1e-30)
-        o_ref[0, :, 0, :] = (acc_ref[...] / l).astype(o_ref.dtype)
+        o_ref[0, 0] = (acc_ref[...] / l).astype(o_ref.dtype)
 
 
 def _attn_kernel_ragged(lens_ref, q_ref, k_ref, v_ref, *rest, scale, block_q,
@@ -145,9 +149,9 @@ def _attn_kernel_ragged(lens_ref, q_ref, k_ref, v_ref, *rest, scale, block_q,
 
     @pl.when(live)
     def _compute():
-        q = q_ref[0, :, 0, :]
-        k = k_ref[0, :, 0, :]
-        v = v_ref[0, :, 0, :]
+        q = q_ref[0, 0]
+        k = k_ref[0, 0]
+        v = v_ref[0, 0]
         if quantized:
             k = _dequant_tile(k, ks_ref, q.dtype)
             v = _dequant_tile(v, vs_ref, q.dtype)
@@ -178,7 +182,7 @@ def _attn_kernel_ragged(lens_ref, q_ref, k_ref, v_ref, *rest, scale, block_q,
         l = jnp.maximum(l_ref[...], 1e-30)
         rows = q_start + jax.lax.broadcasted_iota(jnp.int32, (block_q, 1), 0)
         out = jnp.where(rows < slen, acc_ref[...] / l, 0.0)
-        o_ref[0, :, 0, :] = out.astype(o_ref.dtype)
+        o_ref[0, 0] = out.astype(o_ref.dtype)
 
 
 def flash_attention(
@@ -206,74 +210,60 @@ def flash_attention(
     quantized = k_scale is not None
     assert quantized == (v_scale is not None), "k_scale/v_scale come in pairs"
 
+    # head-major operands: each tile's last two dims are (block, hd)
+    qt = q.transpose(0, 2, 1, 3)                 # (B, H, Sq, hd)
+    kt = k.transpose(0, 2, 1, 3)                 # (B, KVH, Sk, hd)
+    vt = v.transpose(0, 2, 1, 3)
+    q_spec = pl.BlockSpec((1, 1, block_q, hd),
+                          lambda b, h, qi, ki, *_: (b, h, qi, 0))
+    kv_spec = pl.BlockSpec((1, 1, block_k, hd),
+                           lambda b, h, qi, ki, *_: (b, h // G, ki, 0))
+    in_specs = [q_spec, kv_spec, kv_spec]
+    operands = [qt, kt, vt]
+    if quantized:
+        s_spec = pl.BlockSpec((1, 1, block_k, 1),
+                              lambda b, h, qi, ki, *_: (b, h // G, ki, 0))
+        in_specs += [s_spec, s_spec]
+        operands += [s.transpose(0, 2, 1)[..., None]   # (B, KVH, Sk, 1)
+                     for s in (k_scale, v_scale)]
+    scratch = [
+        pltpu.VMEM((block_q, 1), jnp.float32),      # m
+        pltpu.VMEM((block_q, 1), jnp.float32),      # l
+        pltpu.VMEM((block_q, hd), jnp.float32),     # acc
+    ]
+    out_shape = jax.ShapeDtypeStruct((B, H, Sq, hd), q.dtype)
     if seq_lens is not None:
         kernel = functools.partial(
             _attn_kernel_ragged, scale=scale, block_q=block_q,
             block_k=block_k, nk=nk, causal=causal, window=window,
             quantized=quantized,
         )
-        in_specs = [
-            pl.BlockSpec((1, block_q, 1, hd),
-                         lambda b, h, qi, ki, lens: (b, qi, h, 0)),
-            pl.BlockSpec((1, block_k, 1, hd),
-                         lambda b, h, qi, ki, lens: (b, ki, h // G, 0)),
-            pl.BlockSpec((1, block_k, 1, hd),
-                         lambda b, h, qi, ki, lens: (b, ki, h // G, 0)),
-        ]
-        operands = [seq_lens.astype(jnp.int32), q, k, v]
-        if quantized:
-            in_specs += [
-                pl.BlockSpec((1, block_k, 1),
-                             lambda b, h, qi, ki, lens: (b, ki, h // G)),
-                pl.BlockSpec((1, block_k, 1),
-                             lambda b, h, qi, ki, lens: (b, ki, h // G)),
-            ]
-            operands += [k_scale, v_scale]
         grid_spec = pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
             grid=(B, H, nq, nk),
             in_specs=in_specs,
-            out_specs=pl.BlockSpec((1, block_q, 1, hd),
-                                   lambda b, h, qi, ki, lens: (b, qi, h, 0)),
-            scratch_shapes=[
-                pltpu.VMEM((block_q, 1), jnp.float32),
-                pltpu.VMEM((block_q, 1), jnp.float32),
-                pltpu.VMEM((block_q, hd), jnp.float32),
-            ],
+            out_specs=q_spec,
+            scratch_shapes=scratch,
         )
-        return pl.pallas_call(
+        out = pl.pallas_call(
             kernel,
             grid_spec=grid_spec,
-            out_shape=jax.ShapeDtypeStruct((B, Sq, H, hd), q.dtype),
+            out_shape=out_shape,
             interpret=interpret,
-        )(*operands)
+        )(seq_lens.astype(jnp.int32), *operands)
+        return out.transpose(0, 2, 1, 3)
 
     kernel = functools.partial(
         _attn_kernel, scale=scale, block_q=block_q, block_k=block_k,
         nk=nk, causal=causal, window=window, quantized=quantized,
     )
-    in_specs = [
-        pl.BlockSpec((1, block_q, 1, hd), lambda b, h, qi, ki: (b, qi, h, 0)),
-        pl.BlockSpec((1, block_k, 1, hd), lambda b, h, qi, ki: (b, ki, h // G, 0)),
-        pl.BlockSpec((1, block_k, 1, hd), lambda b, h, qi, ki: (b, ki, h // G, 0)),
-    ]
-    operands = [q, k, v]
-    if quantized:
-        in_specs += [
-            pl.BlockSpec((1, block_k, 1), lambda b, h, qi, ki: (b, ki, h // G)),
-            pl.BlockSpec((1, block_k, 1), lambda b, h, qi, ki: (b, ki, h // G)),
-        ]
-        operands += [k_scale, v_scale]
-    return pl.pallas_call(
+    out = pl.pallas_call(
         kernel,
         grid=(B, H, nq, nk),
         in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, block_q, 1, hd), lambda b, h, qi, ki: (b, qi, h, 0)),
-        out_shape=jax.ShapeDtypeStruct((B, Sq, H, hd), q.dtype),
-        scratch_shapes=[
-            pltpu.VMEM((block_q, 1), jnp.float32),      # m
-            pltpu.VMEM((block_q, 1), jnp.float32),      # l
-            pltpu.VMEM((block_q, hd), jnp.float32),     # acc
-        ],
+        out_specs=q_spec,
+        out_shape=out_shape,
+        scratch_shapes=scratch,
         interpret=interpret,
     )(*operands)
+    return out.transpose(0, 2, 1, 3)

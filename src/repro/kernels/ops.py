@@ -1,12 +1,17 @@
 """Jit'd public wrappers around the Pallas kernels.
 
 ``impl`` selects the execution path:
-  "pallas"     — compiled TPU kernel (real hardware)
-  "interpret"  — Pallas interpret mode (kernel body run op-by-op; CPU tests)
-  "xla"        — the pure-XLA fallback with identical semantics
+  "pallas"     — the compiled Mosaic TPU kernel. flash, chunk, decode and
+                 paged decode (native and int8) compile for a TPU v5e at
+                 granite-3-2b's head widths (tests/test_tpu_compile.py) and
+                 match their oracles on the chip (chip_smoke.py). ssd does
+                 not compile: Mosaic has no lowering for its cumsum.
+  "interpret"  — Pallas interpret mode (kernel body run op by op), what the
+                 CPU tests check against ``kernels/ref.py``
+  "xla"        — a pure-XLA path with identical semantics; the default
 
-On this CPU container everything defaults to "xla" for speed; tests validate
-"interpret" against the ref oracles so the TPU path is exercised end to end.
+The served path does not call these wrappers: ``models/attention.py`` runs
+its own XLA attention on every backend.
 """
 from __future__ import annotations
 

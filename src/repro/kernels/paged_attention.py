@@ -12,11 +12,16 @@ not as a materialized (B, L, KVH, hd) copy in HBM:
   the pool page streams HBM->VMEM directly, exactly like the dense kernel
   streams contiguous tiles. Unallocated logical pages (table entry -1)
   clamp to page 0 and are masked in-kernel.
-* **GQA group packing** (as in ``decode_attention``): grid (B, KVH, MP);
-  one tile holds all G = H/KVH query heads of a KV head, so the pool is
-  streamed once per KV head.
-* **Sequential innermost page axis**: online-softmax state (m, l, acc)
-  persists in VMEM scratch across the MP pages of one (batch, kv-head).
+* **Whole-page tiles**: grid (B, MP); one K/V tile is a full pool page
+  (page_size, KVH, hd) and the query tile holds all H heads packed as
+  (KVH, G, hd). The pool is read in place, in its engine layout: a tile
+  that spans the KV-head axis is the one Mosaic can tile without a
+  head-major copy of the pool (a one-head (page_size, 1, hd) slice is
+  refused). A static loop over the KVH heads attends each head's (G, hd)
+  queries to its (page_size, hd) keys — the GQA packing of
+  ``decode_attention``, so each page streams HBM->VMEM once.
+* **Sequential innermost page axis**: online-softmax state (m, l, acc) per
+  KV head persists in VMEM scratch across the MP pages of one row.
 
 Validity of row i of logical page p is ``p * page_size + i <= pos[b]``
 (logical slot j holds absolute position j — paged caches never wrap; they
@@ -36,13 +41,14 @@ NEG_INF = -1e30
 
 
 def _paged_kernel(bt_ref, pos_ref, q_ref, k_ref, v_ref, *rest,
-                  scale, page_size, num_pages_per_req, quantized=False):
+                  scale, page_size, num_pages_per_req, kv_heads,
+                  quantized=False):
     if quantized:
         ks_ref, vs_ref, o_ref, m_ref, l_ref, acc_ref = rest
     else:
         o_ref, m_ref, l_ref, acc_ref = rest
     b = pl.program_id(0)
-    p = pl.program_id(2)
+    p = pl.program_id(1)
 
     @pl.when(p == 0)
     def _init():
@@ -50,39 +56,41 @@ def _paged_kernel(bt_ref, pos_ref, q_ref, k_ref, v_ref, *rest,
         l_ref[...] = jnp.zeros_like(l_ref)
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    q = q_ref[0, 0, :, :]                               # (G, hd)
-    k = k_ref[0, :, 0, :]                               # (ps, hd)
-    v = v_ref[0, :, 0, :]
-    if quantized:
-        # per-token-per-head dequant of the gathered page, right after its
-        # DMA — matches repro.kernels.quant.dequantize_kv (f32 mul, cast)
-        k = (k.astype(jnp.float32) * ks_ref[0, :, 0][:, None]).astype(q.dtype)
-        v = (v.astype(jnp.float32) * vs_ref[0, :, 0][:, None]).astype(q.dtype)
     pos = pos_ref[b]                                    # scalar int32
     allocated = bt_ref[b, p] >= 0
-
-    s = jax.lax.dot_general(
-        q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-    ) * scale                                           # (G, ps)
     logical = p * page_size + jax.lax.iota(jnp.int32, page_size)
     valid = allocated & (logical <= pos)
-    s = jnp.where(valid[None, :], s, NEG_INF)
-
-    m_prev, l_prev = m_ref[...], l_ref[...]             # (G, 1)
-    m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
-    pexp = jnp.exp(s - m_new)
-    alpha = jnp.exp(m_prev - m_new)
-    l_ref[...] = l_prev * alpha + jnp.sum(pexp, axis=-1, keepdims=True)
-    m_ref[...] = m_new
-    acc_ref[...] = acc_ref[...] * alpha + jax.lax.dot_general(
-        pexp.astype(v.dtype), v, (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32,
-    )                                                   # (G, hd)
+    for kv in range(kv_heads):
+        q = q_ref[0, kv]                                # (G, hd)
+        k = k_ref[0, :, kv, :]                          # (ps, hd)
+        v = v_ref[0, :, kv, :]
+        if quantized:
+            # per-token-per-head dequant of the gathered page, right after
+            # its DMA — matches repro.kernels.quant.dequantize_kv (f32 mul,
+            # cast)
+            k = (k.astype(jnp.float32)
+                 * ks_ref[0, :, kv][:, None]).astype(q.dtype)
+            v = (v.astype(jnp.float32)
+                 * vs_ref[0, :, kv][:, None]).astype(q.dtype)
+        s = jax.lax.dot_general(
+            q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
+        ) * scale                                       # (G, ps)
+        s = jnp.where(valid[None, :], s, NEG_INF)
+        m_prev, l_prev = m_ref[kv], l_ref[kv]           # (G, 1)
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+        pexp = jnp.exp(s - m_new)
+        alpha = jnp.exp(m_prev - m_new)
+        l_ref[kv] = l_prev * alpha + jnp.sum(pexp, axis=-1, keepdims=True)
+        m_ref[kv] = m_new
+        acc_ref[kv] = acc_ref[kv] * alpha + jax.lax.dot_general(
+            pexp.astype(v.dtype), v, (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32,
+        )                                               # (G, hd)
 
     @pl.when(p == num_pages_per_req - 1)
     def _finalize():
         l = jnp.maximum(l_ref[...], 1e-30)
-        o_ref[0, 0, :, :] = (acc_ref[...] / l).astype(o_ref.dtype)
+        o_ref[0] = (acc_ref[...] / l).astype(o_ref.dtype)
 
 
 def paged_decode_attention(
@@ -107,39 +115,40 @@ def paged_decode_attention(
 
     kernel = functools.partial(
         _paged_kernel, scale=scale, page_size=page_size,
-        num_pages_per_req=MP, quantized=quantized,
+        num_pages_per_req=MP, kv_heads=KVH, quantized=quantized,
     )
 
-    def page_map(b, kv, p, bt_ref, pos_ref):
+    def page_map(b, p, bt_ref, pos_ref):
         # clamp -1 (unallocated) to 0: the tile is DMA'd but masked in-kernel
-        return (jnp.maximum(bt_ref[b, p], 0), 0, kv, 0)
+        return (jnp.maximum(bt_ref[b, p], 0), 0, 0, 0)
 
-    def scale_map(b, kv, p, bt_ref, pos_ref):
-        return (jnp.maximum(bt_ref[b, p], 0), 0, kv)
+    def scale_map(b, p, bt_ref, pos_ref):
+        return (jnp.maximum(bt_ref[b, p], 0), 0, 0)
 
+    q_spec = pl.BlockSpec((1, KVH, G, hd), lambda b, p, bt, ps_: (b, 0, 0, 0))
     in_specs = [
-        pl.BlockSpec((1, 1, G, hd), lambda b, kv, p, bt, ps_: (b, kv, 0, 0)),
-        pl.BlockSpec((1, page_size, 1, hd), page_map),
-        pl.BlockSpec((1, page_size, 1, hd), page_map),
+        q_spec,
+        pl.BlockSpec((1, page_size, KVH, hd), page_map),
+        pl.BlockSpec((1, page_size, KVH, hd), page_map),
     ]
     operands = [block_tables, pos, qg, k_pages, v_pages]
     if quantized:
         # scale pools (N, ps, KVH) gather by the same block-table indirection
         in_specs += [
-            pl.BlockSpec((1, page_size, 1), scale_map),
-            pl.BlockSpec((1, page_size, 1), scale_map),
+            pl.BlockSpec((1, page_size, KVH), scale_map),
+            pl.BlockSpec((1, page_size, KVH), scale_map),
         ]
         operands += [k_scale, v_scale]
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
-        grid=(B, KVH, MP),
+        grid=(B, MP),
         in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, 1, G, hd), lambda b, kv, p, bt, ps_: (b, kv, 0, 0)),
+        out_specs=q_spec,
         scratch_shapes=[
-            pltpu.VMEM((G, 1), jnp.float32),
-            pltpu.VMEM((G, 1), jnp.float32),
-            pltpu.VMEM((G, hd), jnp.float32),
+            pltpu.VMEM((KVH, G, 1), jnp.float32),
+            pltpu.VMEM((KVH, G, 1), jnp.float32),
+            pltpu.VMEM((KVH, G, hd), jnp.float32),
         ],
     )
     out = pl.pallas_call(

@@ -34,12 +34,11 @@ _EPS = 1e-8  # amax floor: all-zero rows quantize to zeros, scale stays finite
 
 
 def qdtype_of(prec: KVPrecision):
-    """Resolve the spec's storage dtype to a jnp dtype, gating fp8 on
-    actual availability in this jax pin (no install, no silent fallback)."""
+    """Resolve the spec's storage dtype to a jnp dtype (an unknown name
+    raises — no silent fallback)."""
     if not hasattr(jnp, prec.dtype):
         raise ValueError(
-            f"kv_precision dtype {prec.dtype!r} is not available in this "
-            "jax build (fp8 needs jax.numpy.float8_e4m3fn); use 'int8'")
+            f"kv_precision dtype {prec.dtype!r} is not a jax.numpy dtype")
     return jnp.dtype(getattr(jnp, prec.dtype))
 
 

@@ -8,7 +8,11 @@ the whole scan is one kernel launch with no HBM round-trip for the state
 (the GPU version materializes per-chunk states and runs a separate
 state-passing kernel; the TPU grid makes that fusion natural).
 
-Grid: (B, H, nc) — nc innermost/sequential. Per-tile VMEM working set at
+Grid: (B, H, nc) — nc innermost/sequential. The wrapper makes x and dt
+head-major ((B, H, S, P) and (B, H, 1, S)) and reads A from SMEM, so every
+tile's last two dims are ones Mosaic can tile. The kernel still does not
+compile for the TPU: Mosaic has no lowering for the chunk-local cumsum
+(it runs in interpret mode only). Per-tile VMEM working set at
 Q=128, P=64, N=128: x (Q,P), B/C (Q,N), dt/LA (Q,), state (P,N) f32,
 G/M/W (Q,Q) f32 ≈ 0.3 MB.
 
@@ -33,9 +37,9 @@ def _ssd_kernel(x_ref, dt_ref, a_ref, b_ref, c_ref, y_ref, st_ref,
     def _init():
         state_ref[...] = jnp.zeros_like(state_ref)
 
-    x = x_ref[0, :, 0, :].astype(jnp.float32)          # (Q, P)
-    dt = dt_ref[0, :, 0]                               # (Q,) f32
-    A = a_ref[0]                                       # scalar f32 (this head)
+    x = x_ref[0, 0].astype(jnp.float32)                # (Q, P)
+    dt = dt_ref[0, 0, 0]                               # (Q,) f32
+    A = a_ref[pl.program_id(1)]                        # scalar f32 (this head)
     Bm = b_ref[0, :, :].astype(jnp.float32)            # (Q, N)
     Cm = c_ref[0, :, :].astype(jnp.float32)            # (Q, N)
 
@@ -68,7 +72,7 @@ def _ssd_kernel(x_ref, dt_ref, a_ref, b_ref, c_ref, y_ref, st_ref,
     )                                                  # (P, N)
     state_ref[...] = jnp.exp(jnp.sum(dA)) * state + contrib
 
-    y_ref[0, :, 0, :] = y.astype(y_ref.dtype)
+    y_ref[0, 0] = y.astype(y_ref.dtype)
 
     @pl.when(ci == nc - 1)
     def _write_state():
@@ -96,21 +100,23 @@ def ssd_scan(
         kernel,
         grid=(B, H, nc),
         in_specs=[
-            pl.BlockSpec((1, chunk, 1, P), lambda b, h, ci: (b, ci, h, 0)),
-            pl.BlockSpec((1, chunk, 1), lambda b, h, ci: (b, ci, h)),
-            pl.BlockSpec((1,), lambda b, h, ci: (h,)),
+            pl.BlockSpec((1, 1, chunk, P), lambda b, h, ci: (b, h, ci, 0)),
+            pl.BlockSpec((1, 1, 1, chunk), lambda b, h, ci: (b, h, 0, ci)),
+            pl.BlockSpec(memory_space=pltpu.SMEM),
             pl.BlockSpec((1, chunk, N), lambda b, h, ci: (b, ci, 0)),
             pl.BlockSpec((1, chunk, N), lambda b, h, ci: (b, ci, 0)),
         ],
         out_specs=[
-            pl.BlockSpec((1, chunk, 1, P), lambda b, h, ci: (b, ci, h, 0)),
+            pl.BlockSpec((1, 1, chunk, P), lambda b, h, ci: (b, h, ci, 0)),
             pl.BlockSpec((1, 1, P, N), lambda b, h, ci: (b, h, 0, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((B, S, H, P), x.dtype),
+            jax.ShapeDtypeStruct((B, H, S, P), x.dtype),
             jax.ShapeDtypeStruct((B, H, P, N), jnp.float32),
         ],
         scratch_shapes=[pltpu.VMEM((P, N), jnp.float32)],
         interpret=interpret,
-    )(x, dt.astype(jnp.float32), A.astype(jnp.float32), Bm, Cm)
-    return y, st
+    )(x.transpose(0, 2, 1, 3),
+      dt.astype(jnp.float32).transpose(0, 2, 1)[:, :, None, :],
+      A.astype(jnp.float32), Bm, Cm)
+    return y.transpose(0, 2, 1, 3), st

@@ -174,10 +174,7 @@ def run_case(arch: str, shape: str, multi_pod: bool = False,
         moe_parallel=moe_parallel, prefill_block=prefill_block,
     )
     dt = time.time() - t0
-    cost = compiled.cost_analysis() or {}
-    if isinstance(cost, (list, tuple)):  # older jax: [per-device dict]
-        cost = cost[0] if cost else {}
-    cost = dict(cost)
+    cost = dict(compiled.cost_analysis() or {})
     mem = compiled.memory_analysis()
     hlo = compiled.as_text()
     rf = build_roofline(meta["cfg"], meta["case"], n_chips, cost, hlo, mem)

@@ -5,24 +5,18 @@ from __future__ import annotations
 import jax
 
 
-def _axis_type_kwargs(n: int) -> dict:
-    """axis_types only where this jax version supports it (>= 0.5)."""
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is None:
-        return {}
-    return {"axis_types": (axis_type.Auto,) * n}
-
-
 def make_production_mesh(*, multi_pod: bool = False):
     """16x16 = 256 chips per pod; 2 pods = 512 chips when multi_pod."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes, **_axis_type_kwargs(len(axes)))
+    return jax.make_mesh(shape, axes,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_host_mesh():
     """Single-device mesh for CPU tests (axis sizes 1)."""
-    return jax.make_mesh((1, 1), ("data", "model"), **_axis_type_kwargs(2))
+    return jax.make_mesh((1, 1), ("data", "model"),
+                         axis_types=(jax.sharding.AxisType.Auto,) * 2)
 
 
 def batch_axes(mesh) -> tuple:

@@ -25,6 +25,12 @@ the sampled streams are identical at any ``--replicas`` count, batch
 packing, or preemption schedule. Omit them all and the engine serves the
 bit-identical greedy path (argmax, no sampling layer traced).
 
+With several devices in the process (a four-chip host), ``--replicas N``
+places replica i on ``jax.devices()[i % n_devices]``: its params, page
+pools and sync state are committed there, and one process drives every
+chip. ``parse_args`` / ``build`` / ``run`` are the pieces of ``main`` that
+other entry points (``chip_smoke.py``) call to construct the same system.
+
 ``--metrics`` prints the Prometheus text exposition of every engine counter
 at shutdown; ``--trace-out PATH`` records the full request lifecycle and
 writes a Chrome-trace JSON (open in Perfetto); ``--decisions-out PATH``
@@ -35,12 +41,15 @@ and router — and none of them changes a single generated token.
 from __future__ import annotations
 
 import argparse
+import dataclasses
 
 import jax
 import numpy as np
 
 from repro.configs import get_config
+from repro.configs.base import ModelConfig
 from repro.control import ROUTER_KINDS, FleetRouter, LatencyAware
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import init_params
 from repro.obs import OBS_OFF, observability
 from repro.reliability import ConformalScheduler, TenantSLO
@@ -86,7 +95,10 @@ def _parse_tenants(spec: str, quantile: float, error):
     return tuple(sources), tuple(slos)
 
 
-def main():
+def parse_args(argv=None) -> argparse.Namespace:
+    """Parse and validate the launcher's arguments. Adds the derived
+    ``sampling`` (engine-default SamplingParams or None) and the parsed
+    ``tenant_specs`` / ``tenant_slos``."""
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", required=True)
     ap.add_argument("--smoke", action="store_true")
@@ -195,7 +207,9 @@ def main():
     ap.add_argument("--prompt-len", type=int, default=16)
     ap.add_argument("--cache-len", type=int, default=64)
     ap.add_argument("--capacity", type=int, default=32)
-    args = ap.parse_args()
+    ap.add_argument("--max-new-tokens", type=int, default=4,
+                    help="tokens each request generates")
+    args = ap.parse_args(argv)
     if args.paged and args.legacy_loop:
         ap.error("--legacy-loop is a dense-engine comparison path; "
                  "the paged engine has no per-step loop")
@@ -244,7 +258,7 @@ def main():
                  f"got {args.chunk_budget}")
     for name in ("slots", "prompt_len", "cache_len", "page_size",
                  "num_pages", "max_active", "capacity", "horizon",
-                 "raw_rate"):
+                 "raw_rate", "max_new_tokens"):
         if getattr(args, name) < 1:
             ap.error(f"--{name.replace('_', '-')} must be >= 1, "
                      f"got {getattr(args, name)}")
@@ -281,10 +295,33 @@ def main():
     if args.policy == "conformal-slo" and not tenant_slos:
         ap.error("--policy conformal-slo needs at least one tenant with a "
                  "deadline via --tenants (e.g. gold:0.3:1:6,bulk:0.7:0:24)")
+    args.sampling = sampling
+    args.tenant_specs, args.tenant_slos = tenant_specs, tenant_slos
+    return args
 
+
+@dataclasses.dataclass
+class Launch:
+    """The system ``main`` serves: model, engine (or fleet), scheduler,
+    request source and the observability bundle."""
+
+    cfg: ModelConfig
+    params: dict
+    engine: object
+    sched: object
+    src: RequestSource
+    obs: object
+    telemetry: bool
+
+
+def build(args: argparse.Namespace, params=None) -> Launch:
+    """Construct the launcher's system from parsed arguments. ``params``
+    reuses an existing weight tree (random from ``PRNGKey(0)`` otherwise)."""
+    sampling = args.sampling
     cfg = get_config(args.arch, smoke=args.smoke)
-    params = init_params(jax.random.PRNGKey(0), cfg)
-    telemetry = args.metrics or args.trace_out or args.decisions_out
+    if params is None:
+        params = init_params(jax.random.PRNGKey(0), cfg)
+    telemetry = bool(args.metrics or args.trace_out or args.decisions_out)
     obs = observability() if telemetry else OBS_OFF
     if args.paged:
         mk_engine = lambda: PagedEngine(cfg, params, PagedEngineConfig(
@@ -305,8 +342,11 @@ def main():
     if args.replicas > 1:
         router = FleetRouter(kind=args.router,
                              decisions=obs.decisions if telemetry else None)
+        devices = jax.devices()
         engine = ReplicaFleet.build(mk_engine, args.replicas, router=router,
-                                    obs=obs if telemetry else None)
+                                    obs=obs if telemetry else None,
+                                    devices=devices if len(devices) > 1
+                                    else None)
     else:
         engine = mk_engine()
     rates = tuple(float(f) for f in range(1, args.raw_rate + 1))
@@ -330,7 +370,7 @@ def main():
             capacity=args.capacity, obs=sched_obs)
     elif args.policy == "conformal-slo":
         sched = ConformalScheduler(rates=rates, V=args.V,
-                                   tenants=tenant_slos,
+                                   tenants=args.tenant_slos,
                                    slo_gain=args.slo_gain,
                                    capacity=args.capacity, obs=sched_obs)
     elif args.policy == "precision-aware":
@@ -343,12 +383,26 @@ def main():
         sched = StaticScheduler(rate=args.rate, capacity=args.capacity,
                                 obs=sched_obs)
     src = RequestSource(vocab_size=cfg.vocab_size, prompt_len=args.prompt_len,
-                        raw_rate=args.raw_rate, max_new_tokens=4,
+                        raw_rate=args.raw_rate,
+                        max_new_tokens=args.max_new_tokens,
                         min_prompt_len=args.min_prompt_len,
-                        tenants=tenant_specs or None)
-    tr = serve(engine, sched, src, horizon=args.horizon, steps_per_slot=2,
-               fused=not args.legacy_loop, sync_free=args.sync_free,
-               chunked=args.chunked)
+                        tenants=args.tenant_specs or None)
+    return Launch(cfg=cfg, params=params, engine=engine, sched=sched, src=src,
+                  obs=obs, telemetry=telemetry)
+
+
+def run(args: argparse.Namespace, launch: Launch) -> dict:
+    """Serve ``args.horizon`` control slots; returns the serve trace."""
+    return serve(launch.engine, launch.sched, launch.src,
+                 horizon=args.horizon, steps_per_slot=2,
+                 fused=not args.legacy_loop, sync_free=args.sync_free,
+                 chunked=args.chunked)
+
+
+def report(args: argparse.Namespace, launch: Launch, tr: dict) -> None:
+    """Print the launcher's summary lines."""
+    engine, sched, obs = launch.engine, launch.sched, launch.obs
+    sampling, telemetry = args.sampling, launch.telemetry
     print(f"policy={args.policy} served={int(tr['served'].sum())} "
           f"dropped={sched.dropped} "
           f"tail_backlog={float(tr['backlog'][-5:].mean()):.1f} "
@@ -416,6 +470,13 @@ def main():
             print(f"decisions: {obs.decisions.save(args.decisions_out)} "
                   f"({len(obs.decisions.rates)} rate, "
                   f"{len(obs.decisions.routes)} route)")
+
+
+def main(argv=None):
+    enable_compile_cache()
+    args = parse_args(argv)
+    launch = build(args)
+    report(args, launch, run(args, launch))
 
 
 if __name__ == "__main__":

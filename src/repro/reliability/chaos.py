@@ -77,14 +77,10 @@ class _DelayedArray:
         if self._polls > 0:
             self._polls -= 1
             return False
-        inner = self._inner
-        return not hasattr(inner, "is_ready") or inner.is_ready()
+        return self._inner.is_ready()
 
     def copy_to_host_async(self) -> None:
-        try:
-            self._inner.copy_to_host_async()
-        except (AttributeError, RuntimeError):
-            pass
+        self._inner.copy_to_host_async()
 
     def __array__(self, dtype=None):
         a = np.asarray(self._inner)

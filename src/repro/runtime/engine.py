@@ -27,8 +27,8 @@ Sync-free serving (``step_slot_sync``, DESIGN.md §7)
 sampled tokens back to scan for finished requests before it can dispatch
 anything else. ``step_slot_sync`` moves sampling, EOS detection, per-slot
 stop masks, and a generated-token ring buffer into the jitted decode scan
-(``SyncState``; the model state is donated where the backend supports it),
-so the host
+(``SyncState``; the model state is donated, so XLA updates the caches in
+place), so the host
 dispatches the next fused decode from device-resident state alone and only
 *initiates* an async copy of tiny ``done/age/served`` counters. The copy of
 slot t is consumed at slot t+1 — readback overlaps compute — so a
@@ -344,13 +344,14 @@ def _sync_step(sync: SyncState, nxt, sig: _DecodeSig):
     return SyncState(tok, age, sync.budget, done, gen_buf), served
 
 
-# Donating the model state lets XLA reuse the KV caches/pools in place; the
-# CPU backend ignores donation (with a warning), so gate it off there.
+# Donating the model state (argument 1) lets XLA reuse the KV caches/pools
+# in place on every backend; the donated input is deleted at dispatch, so
+# callers rebind it from the outputs and never read it again.
 # SyncState is deliberately NOT donated: the previous slot's pending readback
 # packet still references its done/age/gen_buf arrays until the
 # post-dispatch consume — donating them would delete buffers with a
 # device->host copy outstanding.
-_DONATE = (1,) if jax.default_backend() != "cpu" else ()
+_DONATE = (1,)
 
 
 def _sync_next(sig: _DecodeSig, logits, samp, sync: SyncState):
@@ -616,6 +617,7 @@ class Engine:
         # never see it, so on/off cannot change a single token
         self.obs = obs or OBS_OFF
         self.obs_pid = 0          # replica index (the fleet stamps it)
+        self.device = None        # committed device (see ``place``)
         self._now = 0             # current control slot, for deep emit sites
         B, P = ecfg.batch_slots, ecfg.prompt_len
         self._sig = _DecodeSig.of(ecfg)
@@ -826,6 +828,13 @@ class Engine:
     def export_metrics(self, labels: Optional[dict] = None) -> None:
         """Publish counters() into the obs registry (no-op when off)."""
         self.obs.export(self.counters(), labels)
+
+    def place(self, device) -> None:
+        """Commit this engine's device state — params, model state, sync
+        state — to ``device`` (one replica per chip; see ReplicaFleet)."""
+        self.device = device
+        self.params, self.state, self.sync = jax.device_put(
+            (self.params, self.state, self.sync), device)
 
     def _slot_stats(self, n_active: int, served: int, **extra) -> dict:
         """The per-slot stats dict every step mode returns — one builder
@@ -1113,10 +1122,7 @@ class Engine:
         if extra:
             arrays.update(extra)
         for a in arrays.values():
-            try:
-                a.copy_to_host_async()
-            except (AttributeError, RuntimeError):  # backend without async copy
-                pass
+            a.copy_to_host_async()
         tr = self.obs.trace
         if tr.enabled:
             tr.emit("readback", slot=now, pid=self.obs_pid, what="initiate")
@@ -1129,7 +1135,7 @@ class Engine:
     def _readback_ready(self, p: dict) -> bool:
         """Non-blocking: has the packet's device->host transfer completed?"""
         for a in p["arrays"].values():
-            if hasattr(a, "is_ready") and not a.is_ready():
+            if not a.is_ready():
                 return False
         return True
 
@@ -1142,7 +1148,7 @@ class Engine:
         timeout = getattr(self.ecfg, "readback_timeout_s", 0.0)
         deadline = None
         for name, a in p["arrays"].items():
-            while hasattr(a, "is_ready") and not a.is_ready():
+            while not a.is_ready():
                 if timeout <= 0:
                     break  # unbounded: the asarray below blocks as before
                 now_s = time.monotonic()
@@ -1166,7 +1172,7 @@ class Engine:
         waited = False
         if count_waits:
             for a in p["arrays"].values():
-                if hasattr(a, "is_ready") and not a.is_ready():
+                if not a.is_ready():
                     self.readback_waits += 1
                     waited = True
                     break
@@ -1497,6 +1503,7 @@ class PagedEngine(Engine):
         self.cfg, self.params, self.ecfg = cfg, params, ecfg
         self.obs = obs or OBS_OFF
         self.obs_pid = 0
+        self.device = None
         self._now = 0
         self.MP = ecfg.max_pages_per_req or max(ecfg.cache_len // ps, P // ps + 1)
         self._sig = _DecodeSig.of(ecfg)
@@ -1590,6 +1597,11 @@ class PagedEngine(Engine):
         d = super()._slot_stats(n_active, served, **extra)
         d["occupancy"] = self.occupancy()
         return d
+
+    def place(self, device) -> None:
+        self.device = device
+        self.params, self.pools, self.sync = jax.device_put(
+            (self.params, self.pools, self.sync), device)
 
     # ------------------------------------------------------------------
     def occupancy(self) -> float:

@@ -30,6 +30,13 @@ Compile sharing: engine hot-path jits are module-level, keyed on
 executable, so a 4-replica fleet compiles exactly once (asserted in
 tests/test_fleet.py via ``engine.trace_count``).
 
+Placement: ``build(..., devices=...)`` puts replica i on
+``devices[i % len(devices)]`` — one process drives one replica per chip.
+The replica's engine is constructed under that default device (its page
+pools and sync state are born there), its params are committed there, and
+every later call into it runs under the same default device, so the small
+per-dispatch host arrays land on its chip too.
+
 Failure and drain
 -----------------
 ``fail_replica(i)`` marks a replica dead: its pending device readback is
@@ -50,6 +57,7 @@ idempotent (double-drain is a no-op).
 """
 from __future__ import annotations
 
+import jax
 import numpy as np
 
 from repro.control.router import FleetRouter, ReplicaLoad
@@ -127,12 +135,27 @@ class ReplicaFleet:
     # ------------------------------------------------------------ builders
     @classmethod
     def build(cls, make_engine, n: int, router: FleetRouter | None = None,
-              modes: list | None = None, obs=None,
-              chaos=None) -> "ReplicaFleet":
+              modes: list | None = None, obs=None, chaos=None,
+              devices: list | None = None) -> "ReplicaFleet":
         """Fleet of ``n`` replicas from a zero-arg engine factory (equal
-        geometry => the module-level jit cache gives them one compile)."""
-        return cls([make_engine() for _ in range(n)], router=router,
-                   modes=modes, obs=obs, chaos=chaos)
+        geometry => the module-level jit cache gives them one compile).
+        ``devices`` places replica i on ``devices[i % len(devices)]``."""
+        replicas = []
+        for i in range(n):
+            if devices is None:
+                replicas.append(make_engine())
+                continue
+            dev = devices[i % len(devices)]
+            with jax.default_device(dev):
+                eng = make_engine()
+            eng.place(dev)
+            replicas.append(eng)
+        return cls(replicas, router=router, modes=modes, obs=obs, chaos=chaos)
+
+    def _on(self, i: int):
+        """Context that runs calls into replica ``i`` on its device (an
+        unplaced replica's device is None: the process default)."""
+        return jax.default_device(self.replicas[i].device)
 
     # ------------------------------------------------------- observations
     def queue_len(self) -> int:
@@ -282,7 +305,8 @@ class ReplicaFleet:
             if not self.alive[i]:
                 continue
             mode = self.modes[i] if self.modes is not None else default_mode
-            m = self._STEP[mode](eng, now, n_steps)
+            with self._on(i):
+                m = self._STEP[mode](eng, now, n_steps)
             served += m["served"]
             active += m["active"]
             admitted += m.get("admitted", 0)
@@ -315,7 +339,8 @@ class ReplicaFleet:
         served = 0
         for i, eng in enumerate(self.replicas):
             if self.alive[i]:
-                served += eng.drain()["served"]
+                with self._on(i):
+                    served += eng.drain()["served"]
         return {"served": served}
 
     # ---------------------------------------------------- failure / drain
@@ -379,7 +404,8 @@ class ReplicaFleet:
         eng.pending.clear()
         self.requeues += len(moved)
         self.submit(moved)
-        served = eng.drain()["served"]
+        with self._on(i):
+            served = eng.drain()["served"]
         if served:
             self.served_history.append(served)
         return {"moved": len(moved), "served": served}

@@ -227,10 +227,7 @@ class PolicyScheduler:
                 self._record(backlog, vq, f, lagged=False)
             return f
         f_star = self._dispatch_decision(backlog)
-        try:
-            f_star.copy_to_host_async()
-        except (AttributeError, RuntimeError):
-            pass
+        f_star.copy_to_host_async()
         prev, self._pending_rate = self._pending_rate, f_star
         f = float(prev if prev is not None else f_star)
         self.rate_history.append(f)

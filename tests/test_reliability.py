@@ -11,6 +11,7 @@ import copy
 import os
 from types import SimpleNamespace
 
+import jax.numpy as jnp
 import numpy as np
 import pytest
 
@@ -230,7 +231,7 @@ def test_await_readback_raises_diagnosable_timeout():
 def test_await_readback_tolerates_bounded_delay():
     stub = SimpleNamespace(ecfg=SimpleNamespace(readback_timeout_s=5.0),
                            active=[None], _cursors={})
-    slow = _DelayedArray(np.arange(4), polls=3)
+    slow = _DelayedArray(jnp.arange(4), polls=3)
     Engine._await_readback(stub, {"slot": 0, "arrays": {"age": slow}})
     assert np.asarray(slow).tolist() == [0, 1, 2, 3]
 

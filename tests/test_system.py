@@ -103,9 +103,11 @@ def test_dryrun_entrypoint_smoke():
 
 
 def test_make_production_mesh_is_lazy_import():
-    """Importing mesh.py must not initialize jax devices (module hygiene)."""
+    """Importing mesh.py, the serving launcher or the runtime must not
+    initialize jax devices (module hygiene): a process that imports the
+    package would otherwise hold the accelerator."""
     code = (
-        "import repro.launch.mesh, jax\n"
+        "import repro.launch.mesh, repro.launch.serve, repro.runtime, jax\n"
         "assert not jax._src.xla_bridge._backends, 'devices initialized at import'\n"
     )
     env = dict(os.environ)
