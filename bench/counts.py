@@ -1,0 +1,146 @@
+"""Operations and bytes the served work needs, from shapes and live lengths.
+
+Counted per device step of the served loop: a slot's prompt-chunk part, and
+each of its decode steps. What is counted is what the work needs, whatever
+implements it:
+
+* FLOPs: the projections and MLP of every token processed, attention over
+  each token's live causal context, and the logits where the step needs
+  them (each decode token, and a prompt's last token);
+* bytes: the weights once per step; the logical K/V of each live row's
+  context read once; the new K/V written once.
+
+A gathered copy of the cache, its padding to ``cache_len`` and rows that are
+masked or idle are never counted, so serving through a paged kernel that
+reads only live pages raises the roofline share toward, and never past,
+100%. Peaks come only from ``peaks.json``, keyed by ``device_kind``.
+"""
+from __future__ import annotations
+
+import dataclasses
+import json
+import math
+from pathlib import Path
+
+from model_cfg import head_dim
+
+PEAKS = Path(__file__).resolve().parent / "peaks.json"
+_ITEMSIZE = {"bfloat16": 2, "float16": 2, "float32": 4}
+
+
+def peaks(device_kind: str, path: Path = PEAKS) -> dict:
+    table = json.loads(Path(path).read_text())["devices"]
+    if device_kind not in table:
+        raise KeyError(f"no peaks for device kind {device_kind!r}; "
+                       f"peaks.json has {sorted(table)}")
+    return table[device_kind]
+
+
+@dataclasses.dataclass
+class Work:
+    flops: float = 0.0
+    bytes: float = 0.0
+
+
+def token_flops(spec: dict) -> float:
+    """Projection + MLP FLOPs of one token through every layer."""
+    D, H, KVH, hd = (spec["hidden_size"], spec["num_attention_heads"],
+                     spec["num_key_value_heads"], head_dim(spec))
+    F, L = spec["intermediate_size"], spec["num_hidden_layers"]
+    return 2.0 * L * (D * H * hd + 2 * D * KVH * hd + H * hd * D + 3 * D * F)
+
+
+def attn_flops(spec: dict, keys: int) -> float:
+    """QK^T and PV of one query over ``keys`` live keys, every layer."""
+    return 4.0 * spec["num_hidden_layers"] * spec["num_attention_heads"] \
+        * head_dim(spec) * keys
+
+
+def logits_flops(spec: dict) -> float:
+    return 2.0 * spec["hidden_size"] * spec["vocab_size"]
+
+
+def weight_bytes(spec: dict) -> float:
+    """Every weight once, at the served type (norm scales float32)."""
+    D, H, KVH, hd = (spec["hidden_size"], spec["num_attention_heads"],
+                     spec["num_key_value_heads"], head_dim(spec))
+    F, L, V = spec["intermediate_size"], spec["num_hidden_layers"], spec["vocab_size"]
+    n = V * D + L * (D * H * hd + 2 * D * KVH * hd + H * hd * D + 3 * D * F)
+    return n * _ITEMSIZE[spec["torch_dtype"]] + (2 * L + 1) * D * 4
+
+
+def kv_token_bytes(spec: dict) -> float:
+    """Logical K and V of one token, every layer."""
+    return (2.0 * spec["num_hidden_layers"] * spec["num_key_value_heads"]
+            * head_dim(spec) * _ITEMSIZE[spec["torch_dtype"]])
+
+
+def chunk_work(spec: dict, chunks: list) -> Work:
+    """One dispatch's prompt-chunk part. ``chunks``: (pos0, take, last)
+    per row — ``take`` prompt tokens written from ``pos0``, ``last`` when
+    the chunk ends the prompt (its last token's logits are needed)."""
+    if not chunks:
+        return Work()
+    w = Work(bytes=weight_bytes(spec))
+    kvb = kv_token_bytes(spec)
+    for pos0, take, last in chunks:
+        # query at position p attends p + 1 keys: sum over the chunk
+        keys = take * pos0 + take * (take + 1) / 2
+        w.flops += take * token_flops(spec) + attn_flops(spec, keys)
+        w.flops += logits_flops(spec) if last else 0.0
+        w.bytes += kvb * (pos0 + take)     # context read once, chunk written
+    return w
+
+
+def decode_work(spec: dict, contexts: list) -> Work:
+    """One decode step over the live rows; ``contexts`` holds each row's
+    key count including the new token."""
+    if not contexts:
+        return Work()
+    kvb = kv_token_bytes(spec)
+    n = len(contexts)
+    return Work(
+        flops=n * (token_flops(spec) + logits_flops(spec))
+        + sum(attn_flops(spec, c) for c in contexts),
+        bytes=weight_bytes(spec) + kvb * sum(contexts))
+
+
+def least_seconds(work: Work, peak: dict) -> tuple[float, str]:
+    """The least time the chip could take for ``work``, and its bound."""
+    t_c = work.flops / peak["bf16_flops_per_s"]
+    t_m = work.bytes / peak["hbm_bytes_per_s"]
+    return (t_c, "compute") if t_c >= t_m else (t_m, "memory")
+
+
+def slot_parts(spec: dict, requests: list, n_steps: int, chunk: int) -> dict:
+    """{slot: [Work, ...]}: the device steps of each control slot, rebuilt
+    from the served requests' stamps.
+
+    A request of prompt length L starts chunking in ``start_slot`` and
+    activates in ``first_token_slot`` (its last chunk, whose logits give
+    the first token); chunks of ``chunk`` tokens go one per slot. The
+    activating slot also runs ``n_steps`` decode steps, and each later slot
+    ``n_steps`` more, until the request's tokens are all generated: token
+    k >= 1 comes from a decode step at position L + k - 1 over L + k keys.
+    """
+    chunks: dict = {}
+    steps: dict = {}
+    for r in requests:
+        if r.first_token_slot is None or not r.generated:
+            continue
+        L, f = len(r.tokens), r.first_token_slot
+        s0 = r.start_slot if r.start_slot is not None else f
+        n_chunks = max(1, math.ceil(L / chunk))
+        for i in range(n_chunks):
+            slot = f if i == n_chunks - 1 else min(s0 + i, f)
+            take = min(chunk, L - i * chunk)
+            chunks.setdefault(slot, []).append((i * chunk, take, i == n_chunks - 1))
+        for k in range(1, len(r.generated)):
+            slot = f + max(0, math.ceil((k - n_steps) / n_steps))
+            steps.setdefault((slot, (k - 1) % n_steps), []).append(L + k)
+    out: dict = {}
+    for slot, rows in chunks.items():
+        out.setdefault(slot, []).append(chunk_work(spec, rows))
+    for (slot, _j), ctxs in sorted(steps.items()):
+        out.setdefault(slot, []).append(decode_work(spec, ctxs))
+    return out
