@@ -314,15 +314,19 @@ class Launch:
     telemetry: bool
 
 
-def build(args: argparse.Namespace, params=None) -> Launch:
+def build(args: argparse.Namespace, params=None, obs=None) -> Launch:
     """Construct the launcher's system from parsed arguments. ``params``
-    reuses an existing weight tree (random from ``PRNGKey(0)`` otherwise)."""
+    reuses an existing weight tree (random from ``PRNGKey(0)`` otherwise);
+    ``obs`` an ``Observability`` bundle the caller keeps (else one from the
+    telemetry flags)."""
     sampling = args.sampling
     cfg = get_config(args.arch, smoke=args.smoke)
     if params is None:
         params = init_params(jax.random.PRNGKey(0), cfg)
     telemetry = bool(args.metrics or args.trace_out or args.decisions_out)
-    obs = observability() if telemetry else OBS_OFF
+    if obs is None:
+        obs = observability() if telemetry else OBS_OFF
+    telemetry = telemetry or obs is not OBS_OFF
     if args.paged:
         mk_engine = lambda: PagedEngine(cfg, params, PagedEngineConfig(
             prompt_len=args.prompt_len, cache_len=args.cache_len,

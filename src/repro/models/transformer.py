@@ -311,10 +311,13 @@ def prefill_hidden(stack, h, cfg: ModelConfig, *, cache_len, enc_out=None,
 # ------------------------------------------------------------------- decode
 def _block_decode(kind, p, h, cache, pos, cfg: ModelConfig, *, window):
     if kind in ("attn", "attn_moe"):
-        a, cache = A.attn_decode(
-            p["attn"], rmsnorm(p["ln1"], h, cfg.norm_eps), cache, pos, cfg, window=window
-        )
-        h, _ = _ffn(p, h + a, cfg)
+        with jax.named_scope("attn"):
+            a, cache = A.attn_decode(
+                p["attn"], rmsnorm(p["ln1"], h, cfg.norm_eps), cache, pos, cfg,
+                window=window,
+            )
+        with jax.named_scope("ffn"):
+            h, _ = _ffn(p, h + a, cfg)
         return h, cache
     if kind == "ssm":
         y, cache = S.ssm_decode(p["ssm"], rmsnorm(p["ln1"], h, cfg.norm_eps), cache, cfg)
@@ -394,11 +397,13 @@ def chunk_hidden(stack, h, caches, pos0, valid, reset, cfg: ModelConfig, *,
 
         def body(hh, pc, window=window):
             p, c = pc
-            a, c = A.attn_chunk(
-                p["attn"], rmsnorm(p["ln1"], hh, cfg.norm_eps), c, pos0, valid,
-                cfg, window=window, reset=reset,
-            )
-            hh, _ = _ffn(p, hh + a, cfg)
+            with jax.named_scope("attn"):
+                a, c = A.attn_chunk(
+                    p["attn"], rmsnorm(p["ln1"], hh, cfg.norm_eps), c, pos0,
+                    valid, cfg, window=window, reset=reset,
+                )
+            with jax.named_scope("ffn"):
+                hh, _ = _ffn(p, hh + a, cfg)
             return constrain(hh), c
 
         h, seg_cache = jax.lax.scan(body, h, (params, cache))
@@ -420,11 +425,13 @@ def chunk_hidden_paged(stack, h, pools, block_table, pos0, valid,
 
         def body(hh, pp):
             p, pool_l = pp
-            a, pool_l = A.attn_chunk_paged(
-                p["attn"], rmsnorm(p["ln1"], hh, cfg.norm_eps), pool_l,
-                block_table, pos0, valid, cfg, base=base,
-            )
-            hh, _ = _ffn(p, hh + a, cfg)
+            with jax.named_scope("attn"):
+                a, pool_l = A.attn_chunk_paged(
+                    p["attn"], rmsnorm(p["ln1"], hh, cfg.norm_eps), pool_l,
+                    block_table, pos0, valid, cfg, base=base,
+                )
+            with jax.named_scope("ffn"):
+                hh, _ = _ffn(p, hh + a, cfg)
             return constrain(hh), pool_l
 
         h, seg_pool = jax.lax.scan(body, h, (params, pool))
@@ -483,11 +490,13 @@ def decode_hidden_paged(stack, h, pools, block_table, pos, cfg: ModelConfig):
 
         def body(hh, pp):
             p, pool_l = pp
-            a, pool_l = A.attn_decode_paged(
-                p["attn"], rmsnorm(p["ln1"], hh, cfg.norm_eps), pool_l,
-                block_table, pos, cfg,
-            )
-            hh, _ = _ffn(p, hh + a, cfg)
+            with jax.named_scope("attn"):
+                a, pool_l = A.attn_decode_paged(
+                    p["attn"], rmsnorm(p["ln1"], hh, cfg.norm_eps), pool_l,
+                    block_table, pos, cfg,
+                )
+            with jax.named_scope("ffn"):
+                hh, _ = _ffn(p, hh + a, cfg)
             return hh, pool_l
 
         h, seg_pool = jax.lax.scan(body, h, (params, pool))

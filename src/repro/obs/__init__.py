@@ -36,16 +36,24 @@ from repro.obs.metrics import (
     Metric,
     MetricsRegistry,
     export_counters,
+    export_request_latencies,
     parse_prometheus,
 )
-from repro.obs.trace import EVENT_KINDS, NULL_TRACE, NullRecorder, TraceRecorder
+from repro.obs.trace import (
+    EVENT_KINDS,
+    NULL_TRACE,
+    NullRecorder,
+    TraceRecorder,
+    wall_us,
+)
 
 __all__ = [
     "DEFAULT_BUCKETS", "EVENT_KINDS", "GAUGE_KEYS", "NULL_DECISIONS",
     "NULL_TRACE", "OBS_OFF", "DecisionLog", "Gauge", "Histogram", "Metric",
     "MetricsRegistry", "NullDecisionLog", "NullRecorder", "Observability",
-    "TraceRecorder", "explain_tables", "export_counters", "observability",
-    "parse_prometheus", "replay_rollout",
+    "TraceRecorder", "explain_tables", "export_counters",
+    "export_request_latencies", "observability",
+    "parse_prometheus", "replay_rollout", "wall_us",
 ]
 
 
@@ -70,6 +78,12 @@ class Observability:
         """Publish a counters() dict into the registry (no-op if none)."""
         if self.registry is not None:
             export_counters(self.registry, counters, labels)
+
+    def export_requests(self, requests, labels: Optional[dict] = None) -> None:
+        """Observe finished requests' latency stamps into the registry's
+        histograms (no-op if none)."""
+        if self.registry is not None:
+            export_request_latencies(self.registry, requests, labels)
 
 
 # The process-wide "observability disabled" singleton. Engines default to

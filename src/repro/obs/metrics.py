@@ -37,6 +37,9 @@ GAUGE_KEYS = frozenset({
 })
 
 DEFAULT_BUCKETS = (1.0, 2.0, 4.0, 8.0, 16.0, 32.0, 64.0, float("inf"))
+# request-latency histograms (``repro_*_seconds``), in seconds
+LATENCY_BUCKETS_S = (0.01, 0.025, 0.05, 0.1, 0.25, 0.5, 1.0, 2.5, 5.0, 10.0,
+                     30.0, 60.0, float("inf"))
 
 _NAME_RE = re.compile(r"^[a-zA-Z_:][a-zA-Z0-9_:]*$")
 
@@ -266,4 +269,30 @@ def export_counters(registry: MetricsRegistry, counters: dict,
         else:
             registry.counter(name, f"engine counter {key}", names).set(
                 float(value), **labels)
+    return registry
+
+
+def export_request_latencies(registry: MetricsRegistry, requests,
+                             labels: Optional[dict] = None,
+                             prefix: str = "repro_") -> MetricsRegistry:
+    """Observe finished requests' wall stamps (``Request.*_us``) into two
+    histograms: ``repro_prefill_seconds``, engine claim to the first token
+    on the host, and ``repro_first_token_seconds``, submit to the first
+    token on the host. A request missing a stamp is left out of that
+    histogram; each request should be passed once."""
+    labels = dict(labels or {})
+    names = tuple(sorted(labels))
+    pre = registry.histogram(
+        prefix + "prefill_seconds", "engine claim to first token on the host",
+        names, buckets=LATENCY_BUCKETS_S)
+    first = registry.histogram(
+        prefix + "first_token_seconds", "submit to first token on the host",
+        names, buckets=LATENCY_BUCKETS_S)
+    for r in requests:
+        if r.first_token_us is None:
+            continue
+        if r.admit_us is not None:
+            pre.observe((r.first_token_us - r.admit_us) * 1e-6, **labels)
+        if r.arrival_us is not None:
+            first.observe((r.first_token_us - r.arrival_us) * 1e-6, **labels)
     return registry

@@ -1,22 +1,30 @@
 """Request-lifecycle tracing: bounded ring buffer -> Chrome-trace JSON.
 
-``TraceRecorder`` collects structured events from the serving hot paths —
-arrival, admission, chunk, activation, preemption, requeue, raced_hit,
-route, retirement, dispatch, readback — keyed by request id and control
-slot. Events are stored as tuples in a preallocated ring (oldest dropped
-on overflow, counted), so steady-state recording is an index bump plus a
-tuple build.
+``TraceRecorder`` collects structured events from the serving hot paths:
+lifecycle points (arrival, admission, chunk, activation, preemption,
+requeue, raced_hit, route, retirement) keyed by request id and control
+slot, and host spans (``span()``) at the serve-loop, scheduler and engine
+boundaries. Events are stored as tuples in a preallocated ring (oldest
+dropped on overflow, counted), so steady-state recording is an index bump
+plus a tuple build.
 
-Disabled tracing must be free: engines hold a ``NullRecorder`` by default
-and guard every emit with ``if recorder.enabled`` — the hot path pays one
-attribute load and one branch (the overhead budget
-tests/test_observability.py asserts).
+One clock: ``now()`` is microseconds on the Unix epoch (``time.time_ns``),
+the clock the profiler's ``TraceMe`` stamps host events with. Each span also
+opens a ``jax.profiler.TraceAnnotation`` of its kind, so inside an active
+``jax.profiler`` trace it lands beside the device ops; a ring timestamp
+minus the profile's ``profile_start_time`` is the profile's own offset.
+Spans nest in time: the enclosing span is the parent.
+
+Disabled tracing must be free: engines hold a ``NullRecorder`` by default;
+its ``span()`` hands back one shared no-op context and every ``emit`` site
+is guarded by ``if recorder.enabled``, so the hot path pays one branch or
+one ``with`` on a constant (the overhead budget tests/test_observability.py
+asserts).
 
 ``chrome_trace()`` exports the Chrome trace event format (Perfetto opens
 it directly): ``pid`` = replica, ``tid`` = engine row (so each slot/row is
-one timeline lane), complete events ("X") for spans with a duration
-(dispatch enqueue, readback consume), instant events ("i") for lifecycle
-points. Timestamps are wall-clock microseconds since the recorder's epoch;
+one timeline lane), complete events ("X") for spans, instant events ("i")
+for lifecycle points. Timestamps are the epoch microseconds as recorded;
 ``slot`` (control-slot index) rides in ``args`` — the timeline-reading
 guide is DESIGN.md §11.
 """
@@ -24,6 +32,8 @@ from __future__ import annotations
 
 import json
 import time
+
+from jax.profiler import TraceAnnotation
 
 # Event kinds the runtime emits (DESIGN.md §11 event schema). Not enforced
 # at emit time — the recorder is generic — but tests pin the runtime to it.
@@ -37,9 +47,70 @@ EVENT_KINDS = (
     "raced_hit",     # prefix-cache hit degraded by a concurrent eviction
     "route",         # fleet router picked a replica for the request
     "retirement",    # request finished; row freed
-    "dispatch",      # host enqueue span of one jitted dispatch
-    "readback",      # async counter-copy lifecycle (initiate/consume)
+    # host spans (``span()``), each a complete event with ``dur``
+    "serve.slot",        # one iteration of the serve loop
+    "control.decide",    # Algorithm 1's decision for the slot
+    "control.admit",     # the scheduler hands polled requests to the engine
+    "engine.consume",    # one readback packet consumed (retirements)
+    "engine.wait",       # inside engine.consume: the host waits on the device
+    "engine.admit",      # chunked admission: rows claimed for pending requests
+    "engine.plan",       # the slot's chunk plan (with its preempt-and-replan)
+    "engine.pages",      # page growth and COW fork flushes
+    "engine.upload",     # the step's host->device arguments
+    "engine.dispatch",   # enqueue of one jitted dispatch
+    "engine.readback",   # the async device->host copy is initiated
 )
+
+
+def wall_us() -> float:
+    """Microseconds on the Unix epoch: the profiler's host clock."""
+    return time.time_ns() / 1e3
+
+
+class _Span:
+    """One live span: a ``TraceAnnotation`` while open, one complete event
+    in the ring when closed. ``set(**args)`` adds args known only at the
+    end (a consume's retirement count)."""
+
+    __slots__ = ("_rec", "_kind", "_ids", "_args", "_ts", "_ann")
+
+    def __init__(self, rec, kind: str, ids: tuple, args: dict):
+        self._rec, self._kind, self._ids, self._args = rec, kind, ids, args
+
+    def set(self, **args) -> None:
+        self._args.update(args)
+
+    def __enter__(self):
+        self._ann = TraceAnnotation(self._kind)
+        self._ann.__enter__()
+        self._ts = wall_us()
+        return self
+
+    def __exit__(self, *exc):
+        end = wall_us()
+        self._ann.__exit__(*exc)
+        slot, rid, row, pid = self._ids
+        self._rec.emit(self._kind, slot=slot, rid=rid, row=row, pid=pid,
+                       ts=self._ts, dur=end - self._ts, **self._args)
+        return False
+
+
+class _NullSpan:
+    """The disabled recorder's one shared span: does nothing."""
+
+    __slots__ = ()
+
+    def set(self, **args) -> None:
+        return None
+
+    def __enter__(self):
+        return self
+
+    def __exit__(self, *exc):
+        return False
+
+
+_NULL_SPAN = _NullSpan()
 
 
 class TraceRecorder:
@@ -55,11 +126,16 @@ class TraceRecorder:
         self._head = 0          # next write index
         self._count = 0         # live events (<= capacity)
         self.dropped = 0        # overwritten events
-        self._t0 = time.perf_counter()
 
     def now(self) -> float:
-        """Microseconds since the recorder's epoch (wall clock)."""
-        return (time.perf_counter() - self._t0) * 1e6
+        """Microseconds on the Unix epoch (see ``wall_us``)."""
+        return wall_us()
+
+    def span(self, kind: str, *, slot=None, rid=None, row=None, pid: int = 0,
+             **args):
+        """Context manager timing one host span of ``kind`` (ring + profiler
+        annotation); ``slot``/``rid``/``row``/``pid`` as ``emit`` takes them."""
+        return _Span(self, kind, (slot, rid, row, pid), args)
 
     def emit(self, kind: str, *, slot=None, rid=None, row=None, pid: int = 0,
              ts=None, dur=None, **args) -> None:
@@ -97,7 +173,6 @@ class TraceRecorder:
         self._buf = [None] * self.capacity
         self._head = self._count = 0
         self.dropped = 0
-        self._t0 = time.perf_counter()
 
     # --------------------------------------------------------- exports
     def chrome_trace(self) -> dict:
@@ -137,7 +212,8 @@ class TraceRecorder:
 
 
 class NullRecorder(TraceRecorder):
-    """Disabled recorder: ``enabled`` is False and every emit is a no-op.
+    """Disabled recorder: ``enabled`` is False, every emit is a no-op and
+    every span is the one shared no-op context.
 
     Hot paths check ``enabled`` before building event kwargs, so a disabled
     engine pays one branch per site; ``emit`` still being callable keeps
@@ -151,6 +227,9 @@ class NullRecorder(TraceRecorder):
 
     def emit(self, kind: str, **kw) -> None:  # noqa: ARG002 — deliberate no-op
         return None
+
+    def span(self, kind: str, **kw):  # noqa: ARG002 — deliberate no-op
+        return _NULL_SPAN
 
 
 NULL_TRACE = NullRecorder()

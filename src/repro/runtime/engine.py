@@ -80,7 +80,7 @@ from repro.models.transformer import (
     paged_segments_supported,
     ragged_prefill_supported,
 )
-from repro.obs import OBS_OFF
+from repro.obs import OBS_OFF, wall_us
 from repro.runtime.request import Request
 from repro.runtime.sampling import SamplingParams, row_tables, sample_rows
 
@@ -384,7 +384,8 @@ def _decode_n_sync(params, state, sync, samp, *, n, cfg, sig):
         sync2, served = _sync_step(sync, nxt, sig)
         return (state2, sync2), served
 
-    (state, sync), served = jax.lax.scan(body, (state, sync), jnp.arange(n))
+    with jax.named_scope("decode"):
+        (state, sync), served = jax.lax.scan(body, (state, sync), jnp.arange(n))
     return state, sync, served
 
 
@@ -400,7 +401,8 @@ def _decode_n_sync_paged(params, state, sync, samp, *, n, cfg, sig):
         sync2, served = _sync_step(sync, nxt, sig)
         return (state2, sync2), served
 
-    (state, sync), served = jax.lax.scan(body, (state, sync), jnp.arange(n))
+    with jax.named_scope("decode"):
+        (state, sync), served = jax.lax.scan(body, (state, sync), jnp.arange(n))
     return state, sync, served
 
 
@@ -457,6 +459,11 @@ def _sync_activate(sync: SyncState, logits, final, budgets, samp, *,
     )
 
 
+# the plan arrays each mixed dispatch takes, in argument order
+_CHUNK_ARGS_DENSE = ("toks", "pos0", "valid", "reset", "final", "budgets")
+_CHUNK_ARGS_PAGED = ("toks", "pos0", "valid", "base", "final", "budgets")
+
+
 @partial(jax.jit, static_argnames=("n", "cfg", "sig"), donate_argnums=_DONATE)
 def _chunk_decode_sync(params, state, sync, toks, pos0, valid, reset, final,
                        budgets, samp, *, n, cfg, sig):
@@ -470,9 +477,10 @@ def _chunk_decode_sync(params, state, sync, toks, pos0, valid, reset, final,
     chunk before anything attends it.
     """
     _TRACE_COUNT["n"] += 1
-    logits, state = M.chunk_step(params, state, toks, pos0, valid, reset, cfg,
-                                 shape_window=sig.shape_window)
-    sync = _sync_activate(sync, logits, final, budgets, samp, sig=sig)
+    with jax.named_scope("chunk"):
+        logits, state = M.chunk_step(params, state, toks, pos0, valid, reset,
+                                     cfg, shape_window=sig.shape_window)
+        sync = _sync_activate(sync, logits, final, budgets, samp, sig=sig)
 
     def body(carry, i):
         state, sync = carry
@@ -483,7 +491,8 @@ def _chunk_decode_sync(params, state, sync, toks, pos0, valid, reset, final,
         sync2, served = _sync_step(sync, nxt, sig)
         return (state2, sync2), served
 
-    (state, sync), served = jax.lax.scan(body, (state, sync), jnp.arange(n))
+    with jax.named_scope("decode"):
+        (state, sync), served = jax.lax.scan(body, (state, sync), jnp.arange(n))
     return state, sync, served
 
 
@@ -491,9 +500,10 @@ def _chunk_decode_sync(params, state, sync, toks, pos0, valid, reset, final,
 def _chunk_decode_sync_paged(params, state, sync, toks, pos0, valid, base,
                              final, budgets, samp, *, n, cfg, sig):
     _TRACE_COUNT["n"] += 1
-    logits, state = M.chunk_step_paged(params, state, toks, pos0, valid, cfg,
-                                       base=base)
-    sync = _sync_activate(sync, logits, final, budgets, samp, sig=sig)
+    with jax.named_scope("chunk"):
+        logits, state = M.chunk_step_paged(params, state, toks, pos0, valid,
+                                           cfg, base=base)
+        sync = _sync_activate(sync, logits, final, budgets, samp, sig=sig)
 
     def body(carry, i):
         state, sync = carry
@@ -503,7 +513,8 @@ def _chunk_decode_sync_paged(params, state, sync, toks, pos0, valid, base,
         sync2, served = _sync_step(sync, nxt, sig)
         return (state2, sync2), served
 
-    (state, sync), served = jax.lax.scan(body, (state, sync), jnp.arange(n))
+    with jax.named_scope("decode"):
+        (state, sync), served = jax.lax.scan(body, (state, sync), jnp.arange(n))
     return state, sync, served
 
 
@@ -646,6 +657,7 @@ class Engine:
         self.active: list = [None] * B
         self.pending: list = []
         self.finished: list = []
+        self._exported = 0            # finished requests export_metrics saw
         self.slot_age = np.zeros(B, np.int32)
         self.steps = 0
         self.served_history: list = []
@@ -691,6 +703,10 @@ class Engine:
         return t + sum(c.remaining for c in self._cursors.values())
 
     def submit(self, reqs: list) -> None:
+        now_us = wall_us()
+        for r in reqs:
+            if r.arrival_us is None:   # a fleet requeue keeps the first
+                r.arrival_us = now_us
         tr = self.obs.trace
         if tr.enabled:
             for r in reqs:
@@ -826,8 +842,11 @@ class Engine:
         }
 
     def export_metrics(self, labels: Optional[dict] = None) -> None:
-        """Publish counters() into the obs registry (no-op when off)."""
+        """Publish counters() and the latency stamps of the requests retired
+        since the last export into the obs registry (no-op when off)."""
         self.obs.export(self.counters(), labels)
+        self.obs.export_requests(self.finished[self._exported:], labels)
+        self._exported = len(self.finished)
 
     def place(self, device) -> None:
         """Commit this engine's device state — params, model state, sync
@@ -858,8 +877,13 @@ class Engine:
     def _emit_admission(self, req: Request, row: int, now: int) -> None:
         """Stamp engine-claim time (queue-wait = admit_slot - arrival_slot)
         and record the admission event. Preemption resets the stamp; the
-        re-claim restamps it, like start_slot/first_token_slot."""
+        re-claim restamps it, like start_slot/first_token_slot. A blocking
+        admission already holds the first token on the host (``generated``
+        set), so its first-token stamp is the claim's."""
         req.admit_slot = now
+        req.admit_us = wall_us()
+        if req.generated:
+            req.first_token_us = req.admit_us
         if self._effective(req) is not None:
             self.requests_sampled += 1
         tr = self.obs.trace
@@ -867,7 +891,9 @@ class Engine:
             tr.emit("admission", slot=now, rid=req.rid, row=row,
                     pid=self.obs_pid)
 
-    def _emit_retire(self, req: Request, row: int, slot: int) -> None:
+    def _emit_retire(self, req: Request, row: int, slot: int,
+                     us: Optional[float] = None) -> None:
+        req.finish_us = wall_us() if us is None else us
         tr = self.obs.trace
         if tr.enabled:
             tr.emit("retirement", slot=slot, rid=req.rid, row=row,
@@ -969,14 +995,11 @@ class Engine:
         slot_idx = np.full(B, B, np.int32)  # B = out of range -> scatter drops
         slot_idx[:k] = slots
         batch = {"tokens": jnp.asarray(toks), **self.extra}
-        tr = self.obs.trace
-        t0 = tr.now() if tr.enabled else 0.0
-        logits, new = self._run_prefill(batch, lens, self.ecfg.cache_len)
+        with self.obs.trace.span("engine.dispatch", slot=now, pid=self.obs_pid,
+                                 what="prefill", rows=k):
+            logits, new = self._run_prefill(batch, lens, self.ecfg.cache_len)
+            self.state = _splice_many(self.state, new, jnp.asarray(slot_idx))
         self.prefill_dispatches += 1
-        self.state = _splice_many(self.state, new, jnp.asarray(slot_idx))
-        if tr.enabled:
-            tr.emit("dispatch", slot=now, pid=self.obs_pid, ts=t0,
-                    dur=tr.now() - t0, what="prefill", rows=k)
         if sync:
             budgets = np.zeros(B, np.int32)
             budgets[:k] = [r.max_new_tokens for r in reqs]
@@ -1033,16 +1056,13 @@ class Engine:
                 [r.generated[-1] if r else 0 for r in self.active], jnp.int32
             )
             samp, ages, hist, sig = self._samp_decode_args()
-            tr = self.obs.trace
-            t0 = tr.now() if tr.enabled else 0.0
-            nxt, self.state = _decode_one(self.params, self.state, toks,
-                                          samp, ages, hist,
-                                          cfg=self.cfg, sig=sig)
+            with self.obs.trace.span("engine.dispatch", slot=now,
+                                     pid=self.obs_pid, what="decode", n=1):
+                nxt, self.state = _decode_one(self.params, self.state, toks,
+                                              samp, ages, hist,
+                                              cfg=self.cfg, sig=sig)
             self.decode_dispatches += 1
             self.blocking_syncs += 1
-            if tr.enabled:
-                tr.emit("dispatch", slot=now, pid=self.obs_pid, ts=t0,
-                        dur=tr.now() - t0, what="decode", n=1)
             nxt = np.asarray(nxt)
             for i, r in enumerate(self.active):
                 if r is None:
@@ -1080,17 +1100,14 @@ class Engine:
                 [r.generated[-1] if r else 0 for r in self.active], jnp.int32
             )
             samp, ages, hist, sig = self._samp_decode_args()
-            tr = self.obs.trace
-            t0 = tr.now() if tr.enabled else 0.0
-            all_toks, self.state = _decode_n(
-                self.params, self.state, toks, samp, ages, hist,
-                n=n_steps, cfg=self.cfg, sig=sig,
-            )
+            with self.obs.trace.span("engine.dispatch", slot=now,
+                                     pid=self.obs_pid, what="decode", n=n_steps):
+                all_toks, self.state = _decode_n(
+                    self.params, self.state, toks, samp, ages, hist,
+                    n=n_steps, cfg=self.cfg, sig=sig,
+                )
             self.decode_dispatches += 1
             self.blocking_syncs += 1
-            if tr.enabled:
-                tr.emit("dispatch", slot=now, pid=self.obs_pid, ts=t0,
-                        dur=tr.now() - t0, what="decode", n=n_steps)
             all_toks = np.asarray(all_toks)  # (n_steps, B)
             for i, r in enumerate(self.active):
                 if r is None:
@@ -1121,11 +1138,9 @@ class Engine:
                   "gen": self.sync.gen_buf, "served": served_steps}
         if extra:
             arrays.update(extra)
-        for a in arrays.values():
-            a.copy_to_host_async()
-        tr = self.obs.trace
-        if tr.enabled:
-            tr.emit("readback", slot=now, pid=self.obs_pid, what="initiate")
+        with self.obs.trace.span("engine.readback", slot=now, pid=self.obs_pid):
+            for a in arrays.values():
+                a.copy_to_host_async()
         packet = {"slot": now, "arrays": arrays,
                   "epoch": self._row_epoch.copy()}
         if self._chaos is not None:  # fault-injection seam (reliability)
@@ -1166,45 +1181,50 @@ class Engine:
         copies alone. By protocol this runs *after* the next slot's
         dispatches are in flight, so the read never gates the device
         pipeline; a not-yet-ready array is an overlap miss, tracked in
-        ``readback_waits`` (the host waited, the device never idled)."""
+        ``readback_waits`` (the host waited, the device never idled).
+
+        The packet is where tokens reach the host: a live row showing age
+        >= 1 for the first time gets its ``first_token_us``, and a retired
+        row its ``finish_us``, both at the moment the copy landed."""
         if p is None:
             return 0, []
-        waited = False
-        if count_waits:
-            for a in p["arrays"].values():
-                if not a.is_ready():
-                    self.readback_waits += 1
-                    waited = True
-                    break
-        self._await_readback(p)
-        t0 = self.obs.trace.now() if self.obs.trace.enabled else 0.0
-        done = np.asarray(p["arrays"]["done"])
-        age = np.asarray(p["arrays"]["age"])
-        gen = np.asarray(p["arrays"]["gen"])
-        per_step = [int(x) for x in np.asarray(p["arrays"]["served"])]
-        served = 0
-        for row, req in enumerate(self.active):
-            if req is None or not done[row]:
-                continue
-            if row in self._cursors:
-                continue  # mid-chunked-prefill: the device done flag is the
-                #           previous tenant's — the row isn't live yet
-            if p["epoch"][row] != self._row_epoch[row]:
-                continue  # row re-admitted after this packet was dispatched
-            a = int(age[row])
-            req.generated = [int(t) for t in gen[row, :min(a, gen.shape[1])]]
-            req.finish_slot = p["slot"]
-            self.finished.append(req)
-            self.active[row] = None
-            self.slot_age[row] = 0
-            self._release_row(row)
-            self._emit_retire(req, row, p["slot"])
-            served += 1
         tr = self.obs.trace
-        if tr.enabled:
-            tr.emit("readback", slot=p["slot"], pid=self.obs_pid, ts=t0,
-                    dur=tr.now() - t0, what="consume", retired=served,
-                    waited=waited)
+        with tr.span("engine.consume", slot=p["slot"], pid=self.obs_pid) as sp:
+            waited = False
+            if count_waits:
+                for a in p["arrays"].values():
+                    if not a.is_ready():
+                        self.readback_waits += 1
+                        waited = True
+                        break
+            with tr.span("engine.wait", slot=p["slot"], pid=self.obs_pid):
+                self._await_readback(p)
+                done = np.asarray(p["arrays"]["done"])
+            landed = wall_us()
+            age = np.asarray(p["arrays"]["age"])
+            gen = np.asarray(p["arrays"]["gen"])
+            per_step = [int(x) for x in np.asarray(p["arrays"]["served"])]
+            served = 0
+            for row, req in enumerate(self.active):
+                if req is None or row in self._cursors:
+                    continue  # empty, or mid-chunked-prefill: the device done
+                    #           flag is the previous tenant's — not live yet
+                if p["epoch"][row] != self._row_epoch[row]:
+                    continue  # row re-admitted after this packet was dispatched
+                if req.first_token_us is None and age[row] >= 1:
+                    req.first_token_us = landed
+                if not done[row]:
+                    continue
+                a = int(age[row])
+                req.generated = [int(t) for t in gen[row, :min(a, gen.shape[1])]]
+                req.finish_slot = p["slot"]
+                self.finished.append(req)
+                self.active[row] = None
+                self.slot_age[row] = 0
+                self._release_row(row)
+                self._emit_retire(req, row, p["slot"], us=landed)
+                served += 1
+            sp.set(retired=served, waited=waited)
         extra = served - sum(per_step)
         if extra > 0:  # admission-time finishers (budget <= 1 / EOS first tok)
             per_step = per_step or [0]
@@ -1234,16 +1254,13 @@ class Engine:
         n_active = sum(r is not None for r in self.active)
         if n_active:
             samp, sig = self._samp_args()
-            tr = self.obs.trace
-            t0 = tr.now() if tr.enabled else 0.0
-            self.state, self.sync, served_steps = _decode_n_sync(
-                self.params, self.state, self.sync, samp,
-                n=n_steps, cfg=self.cfg, sig=sig,
-            )
+            with self.obs.trace.span("engine.dispatch", slot=now,
+                                     pid=self.obs_pid, what="decode", n=n_steps):
+                self.state, self.sync, served_steps = _decode_n_sync(
+                    self.params, self.state, self.sync, samp,
+                    n=n_steps, cfg=self.cfg, sig=sig,
+                )
             self.decode_dispatches += 1
-            if tr.enabled:
-                tr.emit("dispatch", slot=now, pid=self.obs_pid, ts=t0,
-                        dur=tr.now() - t0, what="decode", n=n_steps)
             self._post_readback(now, served_steps)
         if not early:
             served_prev, per_step_prev = self._consume_read(prev)
@@ -1283,25 +1300,26 @@ class Engine:
         """
         P = self.ecfg.prompt_len
         k = 0
-        for row in self.free_slots():
-            if not self.pending:
-                break
-            self._validate_chunked(self.pending[0])  # raise before popping
-            req = self.pending.pop(0)
-            L = max(1, min(len(req.tokens), P))
-            if len(req.tokens) > P:
-                req.truncated = True
-            toks = np.asarray(req.tokens[:L], np.int32)
-            if len(toks) < L:
-                toks = np.concatenate(
-                    [toks, np.full(L - len(toks), PAD_ID, np.int32)])
-            self.active[row] = req
-            self.slot_age[row] = 0
-            cached = self._claim_row(row, toks)
-            self._cursors[row] = PrefillCursor(req=req, row=row, toks=toks,
-                                               cached=cached)
-            self._emit_admission(req, row, now)
-            k += 1
+        with self.obs.trace.span("engine.admit", slot=now, pid=self.obs_pid):
+            for row in self.free_slots():
+                if not self.pending:
+                    break
+                self._validate_chunked(self.pending[0])  # raise before popping
+                req = self.pending.pop(0)
+                L = max(1, min(len(req.tokens), P))
+                if len(req.tokens) > P:
+                    req.truncated = True
+                toks = np.asarray(req.tokens[:L], np.int32)
+                if len(toks) < L:
+                    toks = np.concatenate(
+                        [toks, np.full(L - len(toks), PAD_ID, np.int32)])
+                self.active[row] = req
+                self.slot_age[row] = 0
+                cached = self._claim_row(row, toks)
+                self._cursors[row] = PrefillCursor(req=req, row=row, toks=toks,
+                                                   cached=cached)
+                self._emit_admission(req, row, now)
+                k += 1
         return k
 
     def _claim_row(self, row: int, toks: np.ndarray) -> int:
@@ -1411,37 +1429,33 @@ class Engine:
         served_prev, per_step_prev = (self._consume_read(prev) if early
                                       else (0, []))
         admitted = self._admit_chunked(now)
-        plan = self._chunk_plan(n_steps)
+        tr, pid = self.obs.trace, self.obs_pid
+        with tr.span("engine.plan", slot=now, pid=pid):
+            plan = self._chunk_plan(n_steps)
         n_active = sum(r is not None for r in self.active)
-        tr = self.obs.trace
         if plan is not None:
-            samp, sig = self._samp_args()
-            t0 = tr.now() if tr.enabled else 0.0
-            self.state, self.sync, served_steps = _chunk_decode_sync(
-                self.params, self.state, self.sync,
-                jnp.asarray(plan["toks"]), jnp.asarray(plan["pos0"]),
-                jnp.asarray(plan["valid"]), jnp.asarray(plan["reset"]),
-                jnp.asarray(plan["final"]), jnp.asarray(plan["budgets"]),
-                samp, n=n_steps, cfg=self.cfg, sig=sig,
-            )
+            with tr.span("engine.upload", slot=now, pid=pid):
+                samp, sig = self._samp_args()
+                chunk = [jnp.asarray(plan[k]) for k in _CHUNK_ARGS_DENSE]
+            with tr.span("engine.dispatch", slot=now, pid=pid, what="mixed",
+                         n=n_steps, chunk_rows=len(plan["plan"])):
+                self.state, self.sync, served_steps = _chunk_decode_sync(
+                    self.params, self.state, self.sync, *chunk,
+                    samp, n=n_steps, cfg=self.cfg, sig=sig,
+                )
             self.decode_dispatches += 1
-            if tr.enabled:
-                tr.emit("dispatch", slot=now, pid=self.obs_pid, ts=t0,
-                        dur=tr.now() - t0, what="mixed", n=n_steps,
-                        chunk_rows=len(plan["plan"]))
             self._finish_chunk_plan(plan, now)
             self._post_readback(now, served_steps)
         elif n_active:
-            samp, sig = self._samp_args()
-            t0 = tr.now() if tr.enabled else 0.0
-            self.state, self.sync, served_steps = _decode_n_sync(
-                self.params, self.state, self.sync, samp,
-                n=n_steps, cfg=self.cfg, sig=sig,
-            )
+            with tr.span("engine.upload", slot=now, pid=pid):
+                samp, sig = self._samp_args()
+            with tr.span("engine.dispatch", slot=now, pid=pid, what="decode",
+                         n=n_steps):
+                self.state, self.sync, served_steps = _decode_n_sync(
+                    self.params, self.state, self.sync, samp,
+                    n=n_steps, cfg=self.cfg, sig=sig,
+                )
             self.decode_dispatches += 1
-            if tr.enabled:
-                tr.emit("dispatch", slot=now, pid=self.obs_pid, ts=t0,
-                        dur=tr.now() - t0, what="decode", n=n_steps)
             self._post_readback(now, served_steps)
         if not early:
             served_prev, per_step_prev = self._consume_read(prev)
@@ -1552,6 +1566,7 @@ class PagedEngine(Engine):
         self.active = [None] * R
         self.pending = []
         self.finished = []
+        self._exported = 0
         self.slot_age = np.zeros(R, np.int32)
         self.steps = 0
         self.served_history = []
@@ -1681,19 +1696,18 @@ class PagedEngine(Engine):
         if not self._fork_plan:
             return
         R, N = self.ecfg.max_active, self.ecfg.num_pages
-        src = np.zeros(R, np.int32)
-        dst = np.full(R, N, np.int32)
-        for j, (s, d) in enumerate(self._fork_plan.values()):
-            src[j], dst[j] = s, d
-        self._fork_plan.clear()
-        tr = self.obs.trace
-        t0 = tr.now() if tr.enabled else 0.0
-        self.pools = _fork_pages(self.pools, jnp.asarray(src),
-                                 jnp.asarray(dst))
+        tr, now, pid = self.obs.trace, self._now, self.obs_pid
+        with tr.span("engine.pages", slot=now, pid=pid, what="fork"):
+            src = np.zeros(R, np.int32)
+            dst = np.full(R, N, np.int32)
+            for j, (s, d) in enumerate(self._fork_plan.values()):
+                src[j], dst[j] = s, d
+            self._fork_plan.clear()
+            with tr.span("engine.dispatch", slot=now, pid=pid, what="fork",
+                         rows=j + 1):
+                self.pools = _fork_pages(self.pools, jnp.asarray(src),
+                                         jnp.asarray(dst))
         self.fork_dispatches += 1
-        if tr.enabled:
-            tr.emit("dispatch", slot=self._now, pid=self.obs_pid, ts=t0,
-                    dur=tr.now() - t0, what="fork", rows=j + 1)
 
     def step(self, now: int) -> dict:
         raise NotImplementedError("the paged engine has no legacy per-step path")
@@ -1725,10 +1739,7 @@ class PagedEngine(Engine):
         req = self.active[row]
         self._release_row(row)
         self.active[row] = None
-        req.generated = None
-        req.admit_slot = None
-        req.start_slot = None
-        req.first_token_slot = None
+        req.unadmit()
         self.pending.insert(0, req)
         self.preemptions += 1
         tr = self.obs.trace
@@ -1806,15 +1817,13 @@ class PagedEngine(Engine):
             page_idx[j, : n_shared] = self.ecfg.num_pages
         # cache_len == bucket: the dense prefill cache is exactly the prompt
         # rows, ready to scatter into pages (no ring wraparound).
-        tr = self.obs.trace
-        t0 = tr.now() if tr.enabled else 0.0
-        logits, state = self._run_prefill(
-            {"tokens": jnp.asarray(toks)}, lens, bucket)
+        with self.obs.trace.span("engine.dispatch", slot=now, pid=self.obs_pid,
+                                 what="prefill", rows=len(take)):
+            logits, state = self._run_prefill(
+                {"tokens": jnp.asarray(toks)}, lens, bucket)
+            self.pools = _paged_splice(self.pools, state.caches,
+                                       jnp.asarray(page_idx))
         self.prefill_dispatches += 1
-        self.pools = _paged_splice(self.pools, state.caches, jnp.asarray(page_idx))
-        if tr.enabled:
-            tr.emit("dispatch", slot=now, pid=self.obs_pid, ts=t0,
-                    dur=tr.now() - t0, what="prefill", rows=len(take))
         admit_reqs = [req for _row, req, _pages, _L, _ns in take]
         if sync:
             rows_arr = np.full(R, R, np.int32)
@@ -1867,21 +1876,23 @@ class PagedEngine(Engine):
         is reserved chunk by chunk in ``_chunk_reserve``."""
         ps = self.ecfg.page_size
         cleared = []
-        for row, req in enumerate(self.active):
-            if req is None or row in self._cursors:
-                continue
-            need = min(int(self.pos[row]) + n_steps, self.MP * ps)
-            pages = self._extend_pages(row, need)
-            if pages is None:
-                self._preempt(row)
-                cleared.append(row)
-                continue
-            self.block_tables[row, : len(pages)] = pages
-        if sync and cleared:
-            R = self.ecfg.max_active
-            rows_arr = np.full(R, R, np.int32)
-            rows_arr[: len(cleared)] = cleared
-            self.sync = _sync_clear(self.sync, jnp.asarray(rows_arr))
+        with self.obs.trace.span("engine.pages", slot=self._now,
+                                 pid=self.obs_pid):
+            for row, req in enumerate(self.active):
+                if req is None or row in self._cursors:
+                    continue
+                need = min(int(self.pos[row]) + n_steps, self.MP * ps)
+                pages = self._extend_pages(row, need)
+                if pages is None:
+                    self._preempt(row)
+                    cleared.append(row)
+                    continue
+                self.block_tables[row, : len(pages)] = pages
+            if sync and cleared:
+                R = self.ecfg.max_active
+                rows_arr = np.full(R, R, np.int32)
+                rows_arr[: len(cleared)] = cleared
+                self.sync = _sync_clear(self.sync, jnp.asarray(rows_arr))
 
     def step_slot(self, now: int, n_steps: int = 1) -> dict:
         """One control slot: batched admit -> page extension -> scan decode
@@ -1893,8 +1904,6 @@ class PagedEngine(Engine):
         n_active = sum(r is not None for r in self.active)
         per_step = [0] * n_steps
         if n_active:
-            tr = self.obs.trace
-            t0 = tr.now() if tr.enabled else 0.0
             toks = jnp.asarray(
                 [r.generated[-1] if r else 0 for r in self.active], jnp.int32
             )
@@ -1905,16 +1914,15 @@ class PagedEngine(Engine):
                 last_tok=toks,
             )
             samp, ages, hist, sig = self._samp_decode_args()
-            all_toks, state = _decode_n_paged(
-                self.params, state, toks, samp, ages, hist,
-                n=n_steps, cfg=self.cfg, sig=sig,
-            )
+            with self.obs.trace.span("engine.dispatch", slot=now,
+                                     pid=self.obs_pid, what="decode", n=n_steps):
+                all_toks, state = _decode_n_paged(
+                    self.params, state, toks, samp, ages, hist,
+                    n=n_steps, cfg=self.cfg, sig=sig,
+                )
             self.pools = state.pools
             self.decode_dispatches += 1
             self.blocking_syncs += 1
-            if tr.enabled:
-                tr.emit("dispatch", slot=now, pid=self.obs_pid, ts=t0,
-                        dur=tr.now() - t0, what="decode", n=n_steps)
             all_toks = np.asarray(all_toks)  # (n_steps, R)
             for row, req in enumerate(self.active):
                 if req is None:
@@ -1954,8 +1962,6 @@ class PagedEngine(Engine):
         self.occupancy_hwm = self.occupancy()
         n_active = sum(r is not None for r in self.active)
         if n_active:
-            tr = self.obs.trace
-            t0 = tr.now() if tr.enabled else 0.0
             # .copy(): jnp.asarray may alias the numpy buffer (CPU zero-copy)
             # and this path never blocks — the host mutates pos/block_tables
             # before the async decode is guaranteed to have read them.
@@ -1969,15 +1975,14 @@ class PagedEngine(Engine):
                 last_tok=jnp.zeros_like(self.sync.cur_tok),
             )
             samp, sig = self._samp_args()
-            state, self.sync, served_steps = _decode_n_sync_paged(
-                self.params, state, self.sync, samp,
-                n=n_steps, cfg=self.cfg, sig=sig,
-            )
+            with self.obs.trace.span("engine.dispatch", slot=now,
+                                     pid=self.obs_pid, what="decode", n=n_steps):
+                state, self.sync, served_steps = _decode_n_sync_paged(
+                    self.params, state, self.sync, samp,
+                    n=n_steps, cfg=self.cfg, sig=sig,
+                )
             self.pools = state.pools
             self.decode_dispatches += 1
-            if tr.enabled:
-                tr.emit("dispatch", slot=now, pid=self.obs_pid, ts=t0,
-                        dur=tr.now() - t0, what="decode", n=n_steps)
             for row, req in enumerate(self.active):
                 if req is not None:
                     self.pos[row] += n_steps
@@ -2095,10 +2100,7 @@ class PagedEngine(Engine):
         req = self.active[row]
         self._release_row(row)
         self.active[row] = None
-        req.generated = None
-        req.admit_slot = None
-        req.start_slot = None
-        req.first_token_slot = None
+        req.unadmit()
         self.pending.insert(0, req)
         self.preemptions += 1
         tr = self.obs.trace
@@ -2122,53 +2124,53 @@ class PagedEngine(Engine):
         self._flush_forks()   # COW copies land before this slot's chunks
         self.peak_active = max(self.peak_active,
                                sum(r is not None for r in self.active))
-        plan = self._chunk_plan(n_steps)
-        if plan is None and len(self._cursors) > 1 and all(
-                r is None or row in self._cursors
-                for row, r in enumerate(self.active)):
-            # every occupied row is a stalled prefill: no decode will ever
-            # retire and free pages — bounce the youngest prefill so the
-            # oldest can finish (re-prefilled later; greedy-identical)
-            self._preempt_cursor(next(reversed(self._cursors)))
+        tr, pid = self.obs.trace, self.obs_pid
+        with tr.span("engine.plan", slot=now, pid=pid):
             plan = self._chunk_plan(n_steps)
+            if plan is None and len(self._cursors) > 1 and all(
+                    r is None or row in self._cursors
+                    for row, r in enumerate(self.active)):
+                # every occupied row is a stalled prefill: no decode will
+                # ever retire and free pages — bounce the youngest prefill
+                # so the oldest can finish (re-prefilled later;
+                # greedy-identical)
+                self._preempt_cursor(next(reversed(self._cursors)))
+                plan = self._chunk_plan(n_steps)
         self._ensure_pages(n_steps, sync=True)
         self.occupancy_hwm = self.occupancy()
         n_active = sum(r is not None for r in self.active)
         decoding = any(r is not None and row not in self._cursors
                        for row, r in enumerate(self.active))
         if plan is not None or decoding:
-            tr = self.obs.trace
-            t0 = tr.now() if tr.enabled else 0.0
-            # .copy(): see step_slot_sync — the non-blocking loop mutates
-            # pos/block_tables before the async dispatch must have read them
-            state = M.PagedDecodeState(
-                pools=self.pools,
-                block_tables=jnp.asarray(self.block_tables.copy()),
-                pos=jnp.asarray(self.pos.copy()),
-                last_tok=jnp.zeros_like(self.sync.cur_tok),
-            )
-            samp, sig = self._samp_args()
-            if plan is not None:
-                state, self.sync, served_steps = _chunk_decode_sync_paged(
-                    self.params, state, self.sync,
-                    jnp.asarray(plan["toks"]), jnp.asarray(plan["pos0"]),
-                    jnp.asarray(plan["valid"]), jnp.asarray(plan["base"]),
-                    jnp.asarray(plan["final"]), jnp.asarray(plan["budgets"]),
-                    samp, n=n_steps, cfg=self.cfg, sig=sig,
+            with tr.span("engine.upload", slot=now, pid=pid):
+                # .copy(): see step_slot_sync — the non-blocking loop
+                # mutates pos/block_tables before the async dispatch must
+                # have read them
+                state = M.PagedDecodeState(
+                    pools=self.pools,
+                    block_tables=jnp.asarray(self.block_tables.copy()),
+                    pos=jnp.asarray(self.pos.copy()),
+                    last_tok=jnp.zeros_like(self.sync.cur_tok),
                 )
-            else:
-                state, self.sync, served_steps = _decode_n_sync_paged(
-                    self.params, state, self.sync, samp,
-                    n=n_steps, cfg=self.cfg, sig=sig,
-                )
+                samp, sig = self._samp_args()
+                if plan is not None:
+                    chunk = [jnp.asarray(plan[k]) for k in _CHUNK_ARGS_PAGED]
+            with tr.span("engine.dispatch", slot=now, pid=pid,
+                         what="mixed" if plan is not None else "decode",
+                         n=n_steps,
+                         chunk_rows=len(plan["plan"]) if plan else 0):
+                if plan is not None:
+                    state, self.sync, served_steps = _chunk_decode_sync_paged(
+                        self.params, state, self.sync, *chunk,
+                        samp, n=n_steps, cfg=self.cfg, sig=sig,
+                    )
+                else:
+                    state, self.sync, served_steps = _decode_n_sync_paged(
+                        self.params, state, self.sync, samp,
+                        n=n_steps, cfg=self.cfg, sig=sig,
+                    )
             self.pools = state.pools
             self.decode_dispatches += 1
-            if tr.enabled:
-                tr.emit("dispatch", slot=now, pid=self.obs_pid, ts=t0,
-                        dur=tr.now() - t0,
-                        what="mixed" if plan is not None else "decode",
-                        n=n_steps,
-                        chunk_rows=len(plan["plan"]) if plan else 0)
             for row, req in enumerate(self.active):
                 if req is not None and row not in self._cursors:
                     self.pos[row] += n_steps   # decode rows (host mirror)

@@ -361,10 +361,7 @@ class ReplicaFleet:
             eng.active[row] = None
             eng.slot_age[row] = 0
             eng._release_row(row)     # paged: pages back to the pool
-            req.generated = None
-            req.admit_slot = None
-            req.start_slot = None
-            req.first_token_slot = None
+            req.unadmit()
             requeued.append(req)
             if tr.enabled:
                 tr.emit("requeue", rid=req.rid, row=row, pid=i, what="active")

@@ -43,6 +43,21 @@ class Request:
     finish_slot: Optional[int] = None
     generated: Optional[list] = None
     truncated: bool = False       # prompt exceeded the engine's bucket
+    # wall stamps, microseconds on the Unix epoch (repro.obs.wall_us, the
+    # profiler's host clock): first submitted to an engine, the engine
+    # claimed a row, the first generated token reached the host, retired
+    arrival_us: Optional[float] = None
+    admit_us: Optional[float] = None
+    first_token_us: Optional[float] = None
+    finish_us: Optional[float] = None
+
+    def unadmit(self) -> None:
+        """Back to the pre-admission state (preemption, fleet requeue): the
+        re-admission restamps every slot field and every wall stamp but
+        ``arrival_us``."""
+        self.generated = None
+        self.admit_slot = self.start_slot = self.first_token_slot = None
+        self.admit_us = self.first_token_us = None
 
 
 @dataclasses.dataclass(frozen=True)

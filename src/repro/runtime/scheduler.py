@@ -30,7 +30,7 @@ from repro.control import (DriftPlusPenalty, LatencyAware, MemoryAware,
                            Policy, PrecisionAware, Static, TokenBacklogAware)
 from repro.control.policy import drift_plus_penalty_action
 from repro.core.utility import Utility, paper_utility
-from repro.obs import explain_tables
+from repro.obs import NULL_TRACE, explain_tables
 
 # trace counter for the no-retrace regression test: the body runs only when
 # jax traces (not on cached calls), so this counts compiles, not calls.
@@ -108,6 +108,7 @@ class PolicyScheduler:
             self._lam_np = np.asarray(lam, np.float32)
             self._cost_np = np.float32(cost) * self._f_np
         self._decisions = self.obs.decisions if self.obs is not None else None
+        self._trace = getattr(self.obs, "trace", NULL_TRACE)
         self._carry = self.policy.init()
         self._admit_precision = "native"
         self.dropped = 0
@@ -156,18 +157,19 @@ class PolicyScheduler:
         page-pool fill fraction), ``token_backlog`` (pending prompt
         tokens), and ``quant_occupancy`` (quantized-region fill) feed
         observation-driven virtual queues via ``_observe``."""
-        self._observe(occupancy, token_backlog, quant_occupancy)
-        d = self._decisions
-        rec = d is not None and d.enabled
-        vq = self._vq_value() if rec else 0.0
-        if self._static_rate is not None:  # no device round-trip for baselines
-            f = float(self._static_rate)
-        else:
-            f = float(self._dispatch_decision(backlog))
-        self.rate_history.append(f)
-        if rec:
-            self._record(backlog, vq, f, lagged=False)
-        return f
+        with self._trace.span("control.decide", slot=len(self.rate_history)):
+            self._observe(occupancy, token_backlog, quant_occupancy)
+            d = self._decisions
+            rec = d is not None and d.enabled
+            vq = self._vq_value() if rec else 0.0
+            if self._static_rate is not None:  # no device round-trip for baselines
+                f = float(self._static_rate)
+            else:
+                f = float(self._dispatch_decision(backlog))
+            self.rate_history.append(f)
+            if rec:
+                self._record(backlog, vq, f, lagged=False)
+            return f
 
     def _vq_value(self) -> float:
         return float(np.asarray(getattr(self._carry, "value", 0.0)))
@@ -216,35 +218,37 @@ class PolicyScheduler:
         bounded observation delay (the backlog moves by at most one slot's
         arrivals/services). The first call blocks once to seed the pipeline;
         Static policies short-circuit with no device work at all."""
-        self._observe(occupancy, token_backlog, quant_occupancy)
-        d = self._decisions
-        rec = d is not None and d.enabled
-        vq = self._vq_value() if rec else 0.0
-        if self._static_rate is not None:
-            f = float(self._static_rate)
+        with self._trace.span("control.decide", slot=len(self.rate_history)):
+            self._observe(occupancy, token_backlog, quant_occupancy)
+            d = self._decisions
+            rec = d is not None and d.enabled
+            vq = self._vq_value() if rec else 0.0
+            if self._static_rate is not None:
+                f = float(self._static_rate)
+                self.rate_history.append(f)
+                if rec:
+                    self._record(backlog, vq, f, lagged=False)
+                return f
+            f_star = self._dispatch_decision(backlog)
+            f_star.copy_to_host_async()
+            prev, self._pending_rate = self._pending_rate, f_star
+            f = float(prev if prev is not None else f_star)
             self.rate_history.append(f)
             if rec:
-                self._record(backlog, vq, f, lagged=False)
+                # the applied rate is the previous slot's decision; the recorded
+                # decomposition explains THIS slot's argmax (chosen may differ)
+                self._record(backlog, vq, f, lagged=True)
             return f
-        f_star = self._dispatch_decision(backlog)
-        f_star.copy_to_host_async()
-        prev, self._pending_rate = self._pending_rate, f_star
-        f = float(prev if prev is not None else f_star)
-        self.rate_history.append(f)
-        if rec:
-            # the applied rate is the previous slot's decision; the recorded
-            # decomposition explains THIS slot's argmax (chosen may differ)
-            self._record(backlog, vq, f, lagged=True)
-        return f
 
     def admit(self, engine, reqs: list, now: int) -> list:
-        room = max(self.capacity - engine.queue_len(), 0)
-        admitted = reqs[:room]
-        self.dropped += len(reqs) - len(admitted)
-        for r in admitted:
-            r.admit_slot = now
-        engine.submit(admitted)
-        return admitted
+        with self._trace.span("control.admit", slot=now):
+            room = max(self.capacity - engine.queue_len(), 0)
+            admitted = reqs[:room]
+            self.dropped += len(reqs) - len(admitted)
+            for r in admitted:
+                r.admit_slot = now
+            engine.submit(admitted)
+            return admitted
 
 
 def AdaptiveScheduler(

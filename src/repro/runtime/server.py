@@ -25,11 +25,16 @@ by one slot; ``serve`` flushes the tail with ``engine.drain()`` and folds
 it into the final slot. The per-slot ``syncs`` column counts dispatch-
 gating synchronous readbacks (0 in the steady state; the legacy paths pay
 1-2 per slot).
+
+Each slot is one ``serve.slot`` span on the engine's recorder
+(``engine.obs.trace``; DESIGN.md §11), the parent of the scheduler's and
+the engine's spans inside it.
 """
 from __future__ import annotations
 
 import numpy as np
 
+from repro.obs import OBS_OFF
 from repro.runtime.engine import Engine
 from repro.runtime.request import RequestSource
 
@@ -40,56 +45,58 @@ def serve(engine: Engine, scheduler, source: RequestSource, *,
     trace = {"backlog": [], "rate": [], "served": [], "active": [],
              "dropped": [], "dispatches": [], "occupancy": [], "syncs": []}
     paged = hasattr(engine, "occupancy")
+    tr = getattr(engine, "obs", OBS_OFF).trace
     for t in range(horizon):
-        d0 = engine.prefill_dispatches + engine.decode_dispatches
-        s0 = engine.blocking_syncs
-        # the observation is the previous slot's commitment peak: end-of-slot
-        # occupancy dips as retirements free pages, hiding the pressure the
-        # controller must price
-        occ = max(engine.occupancy(), engine.occupancy_hwm) if paged else None
-        tok = engine.token_backlog() if hasattr(engine, "token_backlog") else None
-        qocc = (engine.quant_occupancy()
-                if hasattr(engine, "quant_occupancy") else None)
-        if (sync_free or chunked) and hasattr(scheduler, "control_async"):
-            rate = scheduler.control_async(engine.queue_len(), occupancy=occ,
-                                           token_backlog=tok,
-                                           quant_occupancy=qocc)
-        else:
-            rate = scheduler.control(engine.queue_len(), occupancy=occ,
-                                     token_backlog=tok, quant_occupancy=qocc)
-        # the precision lever (DESIGN.md §14): a policy exposing
-        # admit_precision picks the page region for this slot's admissions
-        # (every latch flip is DecisionLog-recorded inside the scheduler)
-        if occ is not None and hasattr(scheduler, "admit_precision"):
-            chosen = scheduler.admit_precision(occ)
-            if chosen is not None and hasattr(engine, "admit_precision"):
-                engine.admit_precision = chosen
-        reqs = source.poll(t, rate)
-        scheduler.admit(engine, reqs, t)
-        if chunked:
-            m = engine.step_slot_chunked(t, n_steps=steps_per_slot)
-            served = m["served"]
-        elif sync_free:
-            m = engine.step_slot_sync(t, n_steps=steps_per_slot)
-            served = m["served"]
-        elif fused:
-            m = engine.step_slot(t, n_steps=steps_per_slot)
-            served = m["served"]
-        else:
-            served = 0
-            for _ in range(steps_per_slot):
-                m = engine.step(t)
-                served += m["served"]
-        trace["backlog"].append(engine.queue_len())
-        trace["rate"].append(rate)
-        trace["served"].append(served)
-        trace["active"].append(m["active"])
-        trace["dropped"].append(scheduler.dropped)
-        trace["dispatches"].append(
-            engine.prefill_dispatches + engine.decode_dispatches - d0
-        )
-        trace["occupancy"].append(engine.occupancy_hwm if paged else 0.0)
-        trace["syncs"].append(engine.blocking_syncs - s0)
+        with tr.span("serve.slot", slot=t):
+            d0 = engine.prefill_dispatches + engine.decode_dispatches
+            s0 = engine.blocking_syncs
+            # the observation is the previous slot's commitment peak: end-of-slot
+            # occupancy dips as retirements free pages, hiding the pressure the
+            # controller must price
+            occ = max(engine.occupancy(), engine.occupancy_hwm) if paged else None
+            tok = engine.token_backlog() if hasattr(engine, "token_backlog") else None
+            qocc = (engine.quant_occupancy()
+                    if hasattr(engine, "quant_occupancy") else None)
+            if (sync_free or chunked) and hasattr(scheduler, "control_async"):
+                rate = scheduler.control_async(engine.queue_len(), occupancy=occ,
+                                               token_backlog=tok,
+                                               quant_occupancy=qocc)
+            else:
+                rate = scheduler.control(engine.queue_len(), occupancy=occ,
+                                         token_backlog=tok, quant_occupancy=qocc)
+            # the precision lever (DESIGN.md §14): a policy exposing
+            # admit_precision picks the page region for this slot's admissions
+            # (every latch flip is DecisionLog-recorded inside the scheduler)
+            if occ is not None and hasattr(scheduler, "admit_precision"):
+                chosen = scheduler.admit_precision(occ)
+                if chosen is not None and hasattr(engine, "admit_precision"):
+                    engine.admit_precision = chosen
+            reqs = source.poll(t, rate)
+            scheduler.admit(engine, reqs, t)
+            if chunked:
+                m = engine.step_slot_chunked(t, n_steps=steps_per_slot)
+                served = m["served"]
+            elif sync_free:
+                m = engine.step_slot_sync(t, n_steps=steps_per_slot)
+                served = m["served"]
+            elif fused:
+                m = engine.step_slot(t, n_steps=steps_per_slot)
+                served = m["served"]
+            else:
+                served = 0
+                for _ in range(steps_per_slot):
+                    m = engine.step(t)
+                    served += m["served"]
+            trace["backlog"].append(engine.queue_len())
+            trace["rate"].append(rate)
+            trace["served"].append(served)
+            trace["active"].append(m["active"])
+            trace["dropped"].append(scheduler.dropped)
+            trace["dispatches"].append(
+                engine.prefill_dispatches + engine.decode_dispatches - d0
+            )
+            trace["occupancy"].append(engine.occupancy_hwm if paged else 0.0)
+            trace["syncs"].append(engine.blocking_syncs - s0)
     if (sync_free or chunked) and trace["served"]:
         # flush the in-flight slot's readback so totals match the synchronous
         # paths; its completions belong to the last dispatched slot
