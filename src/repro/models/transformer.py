@@ -383,30 +383,89 @@ def chunked_prefill_supported(cfg: ModelConfig) -> bool:
     return ragged_prefill_supported(cfg)
 
 
+def _chunk_rows(valid: jax.Array) -> tuple[jax.Array, jax.Array]:
+    """(rows, n_rows): the rows with ``valid > 0`` in ascending order,
+    padded with B, and their count. The order changes nothing: each row
+    writes only its own pages, cache row and staging row."""
+    B = valid.shape[0]
+    on = valid > 0
+    return (jnp.nonzero(on, size=B, fill_value=B)[0].astype(jnp.int32),
+            jnp.sum(on, dtype=jnp.int32))
+
+
+def _chunk_layer_rows(params, l, h, cache, rows, n_rows, attend,
+                      cfg: ModelConfig):
+    """Layer ``l``'s chunk attention + FFN for the rows that carry a chunk,
+    one row per iteration: ``rows[:n_rows]`` (engine rows; the tail pads
+    are never visited). ``n_rows`` is a device value, so one program serves
+    every count of chunk rows; rows that carry no chunk do no work and
+    keep their ``h`` (their logits are discarded by the caller).
+
+    Each iteration reads the layer's weights once, so k chunk rows cost k
+    weight reads and k one-row attentions — near a ``[1, C]`` pass for the
+    usual one or two rows; at k = B the cost model puts it near the padded
+    ``[B, C]`` pass (an estimate, not measured).
+    The weights are sliced from the segment's stacked ``params`` inside
+    the loop body at an index the barrier ties to the iteration: XLA then
+    fuses the slice into the matmuls, where a loop-invariant slice is
+    hoisted out of the loop and copied whole (on a v5e at granite-3-2b's
+    widths, ~14 ms of weight copies per mixed dispatch).
+    ``attend(x, cache, r, p)`` -> (a, cache) is the layer's attention for
+    engine row r on x (1, C, D); ``cache`` is carried and updated in place.
+    """
+    def body(i, carry):
+        hh, c = carry
+        r = rows[i]
+        li, _ = jax.lax.optimization_barrier((l, i))
+        p = jax.tree.map(lambda w: w[li], params)
+        x = jax.lax.dynamic_slice_in_dim(hh, r, 1)
+        with jax.named_scope("attn"):
+            a, c = attend(rmsnorm(p["ln1"], x, cfg.norm_eps), c, r, p)
+        with jax.named_scope("ffn"):
+            x, _ = _ffn(p, x + a, cfg)
+        return jax.lax.dynamic_update_slice_in_dim(hh, x, r, 0), c
+
+    return jax.lax.fori_loop(0, n_rows, body, (h, cache))
+
+
+def _take_row(tree, r):
+    return jax.tree.map(lambda x: jax.lax.dynamic_slice_in_dim(x, r, 1), tree)
+
+
+def _put_row(tree, one, r):
+    return jax.tree.map(
+        lambda x, y: jax.lax.dynamic_update_slice_in_dim(x, y, r, 0), tree, one)
+
+
 def chunk_hidden(stack, h, caches, pos0, valid, reset, cfg: ModelConfig, *,
                  shape_window: Optional[int] = None):
     """One prompt-chunk pass over (B, C, D); mirrors ``prefill_hidden``'s
     per-layer op order (attn -> residual -> FFN -> constrain) with
-    ``attn_chunk`` writing K/V at per-row offsets. Returns (h, caches)."""
+    ``attn_chunk`` writing K/V at per-row offsets. Only the rows with
+    ``valid > 0`` compute (``_chunk_layer_rows``). Returns (h, caches)."""
     segs = plan_segments(cfg, "decoder")
+    rows, n_rows = _chunk_rows(valid)
     new_caches = []
     for seg, params, cache in zip(segs, stack, caches, strict=True):
         if seg.kind != "attn":
             raise ValueError(f"chunked prefill is not supported for {seg.kind!r} blocks")
         window = _window_for(seg.kind, cfg, shape_window)
 
-        def body(hh, pc, window=window):
-            p, c = pc
-            with jax.named_scope("attn"):
-                a, c = A.attn_chunk(
-                    p["attn"], rmsnorm(p["ln1"], hh, cfg.norm_eps), c, pos0,
-                    valid, cfg, window=window, reset=reset,
+        def body(hh, lc, params=params, window=window):
+            l, c = lc
+
+            def attend(x, c, r, p):
+                a, one = A.attn_chunk(
+                    p["attn"], x, _take_row(c, r), pos0[r][None],
+                    valid[r][None], cfg, window=window, reset=reset[r][None],
                 )
-            with jax.named_scope("ffn"):
-                hh, _ = _ffn(p, hh + a, cfg)
+                return a, _put_row(c, one, r)
+
+            hh, c = _chunk_layer_rows(params, l, hh, c, rows, n_rows, attend,
+                                      cfg)
             return constrain(hh), c
 
-        h, seg_cache = jax.lax.scan(body, h, (params, cache))
+        h, seg_cache = jax.lax.scan(body, h, (jnp.arange(seg.n), cache))
         new_caches.append(seg_cache)
     return h, new_caches
 
@@ -416,25 +475,39 @@ def chunk_hidden_paged(stack, h, pools, block_table, pos0, valid,
     """``chunk_hidden`` against the shared page pools (one block table for
     the whole stack, like ``decode_hidden_paged``). ``base`` (B,) is the
     per-row prefix-cache hit length — under a lossy precision it splits
-    attention between pool pages [0, base) and the native staging buffer."""
+    attention between pool pages [0, base) and the native staging buffer,
+    whose row r is the engine row's."""
     segs = plan_segments(cfg, "decoder")
+    rows, n_rows = _chunk_rows(valid)
+    if base is None:
+        base = jnp.zeros_like(valid)
     new_pools = []
     for seg, params, pool in zip(segs, stack, pools, strict=True):
         if seg.kind != "attn":
             raise ValueError(f"chunked prefill is not supported for {seg.kind!r} blocks")
 
-        def body(hh, pp):
-            p, pool_l = pp
-            with jax.named_scope("attn"):
+        def body(hh, lp, params=params):
+            l, pool_l = lp
+
+            def attend(x, pool_l, r, p):
+                stage = (pool_l.stage_k, pool_l.stage_v)
+                if pool_l.stage_k is not None:
+                    sk, sv = _take_row(stage, r)
+                    pool_l = pool_l._replace(stage_k=sk, stage_v=sv)
                 a, pool_l = A.attn_chunk_paged(
-                    p["attn"], rmsnorm(p["ln1"], hh, cfg.norm_eps), pool_l,
-                    block_table, pos0, valid, cfg, base=base,
+                    p["attn"], x, pool_l, block_table[r][None], pos0[r][None],
+                    valid[r][None], cfg, base=base[r][None],
                 )
-            with jax.named_scope("ffn"):
-                hh, _ = _ffn(p, hh + a, cfg)
+                if pool_l.stage_k is not None:
+                    sk, sv = _put_row(stage, (pool_l.stage_k, pool_l.stage_v), r)
+                    pool_l = pool_l._replace(stage_k=sk, stage_v=sv)
+                return a, pool_l
+
+            hh, pool_l = _chunk_layer_rows(params, l, hh, pool_l, rows, n_rows,
+                                           attend, cfg)
             return constrain(hh), pool_l
 
-        h, seg_pool = jax.lax.scan(body, h, (params, pool))
+        h, seg_pool = jax.lax.scan(body, h, (jnp.arange(seg.n), pool))
         new_pools.append(seg_pool)
     return h, new_pools
 

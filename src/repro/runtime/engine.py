@@ -475,6 +475,9 @@ def _chunk_decode_sync(params, state, sync, toks, pos0, valid, reset, final,
     discards their (masked) decode compute; the one masked write they make —
     K/V of a dummy token at their next chunk offset — is overwritten by that
     chunk before anything attends it.
+
+    Only the rows with ``valid > 0`` run the chunk pass (a device-valued
+    trip count: one program whatever the number of chunk rows).
     """
     _TRACE_COUNT["n"] += 1
     with jax.named_scope("chunk"):
@@ -676,6 +679,8 @@ class Engine:
         self.prefix_hits = 0
         self.prefix_forks = 0
         self.fork_dispatches = 0
+        self.mixed_dispatches = 0     # chunk+decode dispatches
+        self.chunk_rows = 0           # rows those dispatches chunked
         self.eviction_raced_hits = 0
         self.occupancy_hwm = 0.0
         # admission epoch per row: a readback packet only retires a row if
@@ -817,6 +822,8 @@ class Engine:
             "prefill_dispatches": self.prefill_dispatches,
             "decode_dispatches": self.decode_dispatches,
             "fork_dispatches": self.fork_dispatches,
+            "mixed_dispatches": self.mixed_dispatches,
+            "chunk_rows": self.chunk_rows,
             "blocking_syncs": self.blocking_syncs,
             "readback_waits": self.readback_waits,
             "preemptions": self.preemptions,
@@ -1392,6 +1399,8 @@ class Engine:
         may retire it again) and its epoch bumps, so done-flag packets from
         pre-activation dispatches can never retire it (they carry the old
         epoch or meet the cursor guard)."""
+        self.mixed_dispatches += 1
+        self.chunk_rows += len(plan["plan"])
         tr = self.obs.trace
         for row, cur, take, fin in plan["plan"]:
             if not cur.started:
@@ -1560,6 +1569,8 @@ class PagedEngine(Engine):
         self.prefix_hits = 0          # prompt tokens served from the cache
         self.prefix_forks = 0         # COW forks of partially-matched pages
         self.fork_dispatches = 0
+        self.mixed_dispatches = 0
+        self.chunk_rows = 0
         self.block_tables = np.full((R, self.MP), -1, np.int32)
         self.pos = np.zeros(R, np.int32)
         self.sync = sync_state_init(R, self._gen_cap)

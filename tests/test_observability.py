@@ -191,6 +191,7 @@ def test_counters_key_parity_dense_vs_paged():
     cd, cp = dense.counters(), paged.counters()
     assert set(cd) == set(cp)
     assert cd["preemptions"] == 0 and "pages_used" in cd
+    assert {"mixed_dispatches", "chunk_rows"} <= set(cd)
     for k in cd:
         assert isinstance(cd[k], (int, float)), k
 
