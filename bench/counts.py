@@ -1,8 +1,10 @@
 """Operations and bytes the served work needs, from shapes and live lengths.
 
 Counted per device step of the served loop: a slot's prompt-chunk part, and
-each of its decode steps. What is counted is what the work needs, whatever
-implements it:
+each of its decode steps, by the configuration's architecture module
+(``arch/<arch>.py``: ``chunk_work``, ``decode_work``), since what a step
+needs depends on the block. What is counted is what the work needs, whatever
+implements it; for the dense block:
 
 * FLOPs: the projections and MLP of every token processed, attention over
   each token's live causal context, and the logits where the step needs
@@ -22,10 +24,8 @@ import json
 import math
 from pathlib import Path
 
-from model_cfg import head_dim
-
 PEAKS = Path(__file__).resolve().parent / "peaks.json"
-_ITEMSIZE = {"bfloat16": 2, "float16": 2, "float32": 4}
+ITEMSIZE = {"bfloat16": 2, "float16": 2, "float32": 4}
 
 
 def peaks(device_kind: str, path: Path = PEAKS) -> dict:
@@ -42,69 +42,6 @@ class Work:
     bytes: float = 0.0
 
 
-def token_flops(spec: dict) -> float:
-    """Projection + MLP FLOPs of one token through every layer."""
-    D, H, KVH, hd = (spec["hidden_size"], spec["num_attention_heads"],
-                     spec["num_key_value_heads"], head_dim(spec))
-    F, L = spec["intermediate_size"], spec["num_hidden_layers"]
-    return 2.0 * L * (D * H * hd + 2 * D * KVH * hd + H * hd * D + 3 * D * F)
-
-
-def attn_flops(spec: dict, keys: int) -> float:
-    """QK^T and PV of one query over ``keys`` live keys, every layer."""
-    return 4.0 * spec["num_hidden_layers"] * spec["num_attention_heads"] \
-        * head_dim(spec) * keys
-
-
-def logits_flops(spec: dict) -> float:
-    return 2.0 * spec["hidden_size"] * spec["vocab_size"]
-
-
-def weight_bytes(spec: dict) -> float:
-    """Every weight once, at the served type (norm scales float32)."""
-    D, H, KVH, hd = (spec["hidden_size"], spec["num_attention_heads"],
-                     spec["num_key_value_heads"], head_dim(spec))
-    F, L, V = spec["intermediate_size"], spec["num_hidden_layers"], spec["vocab_size"]
-    n = V * D + L * (D * H * hd + 2 * D * KVH * hd + H * hd * D + 3 * D * F)
-    return n * _ITEMSIZE[spec["torch_dtype"]] + (2 * L + 1) * D * 4
-
-
-def kv_token_bytes(spec: dict) -> float:
-    """Logical K and V of one token, every layer."""
-    return (2.0 * spec["num_hidden_layers"] * spec["num_key_value_heads"]
-            * head_dim(spec) * _ITEMSIZE[spec["torch_dtype"]])
-
-
-def chunk_work(spec: dict, chunks: list) -> Work:
-    """One dispatch's prompt-chunk part. ``chunks``: (pos0, take, last)
-    per row — ``take`` prompt tokens written from ``pos0``, ``last`` when
-    the chunk ends the prompt (its last token's logits are needed)."""
-    if not chunks:
-        return Work()
-    w = Work(bytes=weight_bytes(spec))
-    kvb = kv_token_bytes(spec)
-    for pos0, take, last in chunks:
-        # query at position p attends p + 1 keys: sum over the chunk
-        keys = take * pos0 + take * (take + 1) / 2
-        w.flops += take * token_flops(spec) + attn_flops(spec, keys)
-        w.flops += logits_flops(spec) if last else 0.0
-        w.bytes += kvb * (pos0 + take)     # context read once, chunk written
-    return w
-
-
-def decode_work(spec: dict, contexts: list) -> Work:
-    """One decode step over the live rows; ``contexts`` holds each row's
-    key count including the new token."""
-    if not contexts:
-        return Work()
-    kvb = kv_token_bytes(spec)
-    n = len(contexts)
-    return Work(
-        flops=n * (token_flops(spec) + logits_flops(spec))
-        + sum(attn_flops(spec, c) for c in contexts),
-        bytes=weight_bytes(spec) + kvb * sum(contexts))
-
-
 def least_seconds(work: Work, peak: dict) -> tuple[float, str]:
     """The least time the chip could take for ``work``, and its bound."""
     t_c = work.flops / peak["bf16_flops_per_s"]
@@ -112,9 +49,10 @@ def least_seconds(work: Work, peak: dict) -> tuple[float, str]:
     return (t_c, "compute") if t_c >= t_m else (t_m, "memory")
 
 
-def slot_parts(spec: dict, requests: list, n_steps: int, chunk: int) -> dict:
+def slot_parts(arch, spec: dict, requests: list, n_steps: int, chunk: int) -> dict:
     """{slot: [Work, ...]}: the device steps of each control slot, rebuilt
-    from the served requests' stamps.
+    from the served requests' stamps. The timeline is the serve loop's;
+    ``arch`` counts each step's work.
 
     A request of prompt length L starts chunking in ``start_slot`` and
     activates in ``first_token_slot`` (its last chunk, whose logits give
@@ -140,7 +78,7 @@ def slot_parts(spec: dict, requests: list, n_steps: int, chunk: int) -> dict:
             steps.setdefault((slot, (k - 1) % n_steps), []).append(L + k)
     out: dict = {}
     for slot, rows in chunks.items():
-        out.setdefault(slot, []).append(chunk_work(spec, rows))
+        out.setdefault(slot, []).append(arch.chunk_work(spec, rows))
     for (slot, _j), ctxs in sorted(steps.items()):
-        out.setdefault(slot, []).append(decode_work(spec, ctxs))
+        out.setdefault(slot, []).append(arch.decode_work(spec, ctxs))
     return out

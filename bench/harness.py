@@ -229,6 +229,7 @@ def load_manifest(root: Path = ROOT) -> dict:
 class Cell:
     name: str
     spec: dict            # configuration file
+    arch: object          # its architecture module (model_cfg's contract)
     mix: dict             # traffic file
     chips: int
     end_to_end: list      # manifest entries reported with --trace 0
@@ -242,12 +243,14 @@ def load_cell(manifest: dict, workload: str, bench_dir: Path = BENCH_DIR) -> Cel
         raise KeyError(f"no workload {workload!r} in BENCHMARK.json")
     cfgs = {c["name"]: c for c in manifest["configs"]}
     spec = model_cfg.load(wl["config"], bench_dir / "configs")
+    # a directory of fixtures may bring architectures beside the benchmark's
+    arch = model_cfg.load_arch(spec["arch"], bench_dir / "arch", model_cfg.ARCH_DIR)
     if cfgs[wl["config"]]["reduced"] != spec["reduced"]:
         raise ValueError(f"{wl['config']}: BENCHMARK.json and the file list "
                          "different reduced keys")
     mix = traffic_gen.load_mix(wl["traffic"], bench_dir / "traffic")
     limits = json.loads((bench_dir / "limits" / f"{workload}.json").read_text())
-    return Cell(name=workload, spec=spec, mix=mix, chips=wl["chips"],
+    return Cell(name=workload, spec=spec, arch=arch, mix=mix, chips=wl["chips"],
                 end_to_end=manifest["end_to_end"], per_layer=manifest["per_layer"],
                 limits=limits)
 
@@ -317,6 +320,7 @@ class RunData:
     """What one run measured; every metric reader takes this."""
 
     spec: dict
+    arch: object              # the configuration's architecture module
     mix: dict
     seconds: float
     n_steps: int
@@ -372,7 +376,8 @@ class RunData:
 
     def traced_work(self) -> list:
         """The device steps (``counts.Work``) of the traced segment's slots."""
-        parts = counts.slot_parts(self.spec, [s.req for s in self.done()],
+        parts = counts.slot_parts(self.arch, self.spec,
+                                  [s.req for s in self.done()],
                                   self.n_steps, self.chunk)
         return [w for t in self.traced_slots() for w in parts.get(t, [])]
 
@@ -433,8 +438,8 @@ def _gap_stats(gaps: list) -> dict:
             "compared_tokens": int(g.size)}
 
 
-def compare(spec: dict, flat: dict, sample: list, pad_to: int, rows_pad: int,
-            control: bool = False) -> dict:
+def compare(arch, spec: dict, flat: dict, sample: list, pad_to: int,
+            rows_pad: int, control: bool = False) -> dict:
     """Gaps by which each served token's reference logit lies below the
     reference's best at its position (0 where they agree), over the sample:
     the widest (the number compared) and their mean over every compared
@@ -446,11 +451,11 @@ def compare(spec: dict, flat: dict, sample: list, pad_to: int, rows_pad: int,
         toks = np.asarray(s.req.generated, np.int32)
         ids = np.concatenate([s.prompt, toks[:-1]]).astype(np.int32)
         rows = np.arange(len(s.prompt) - 1, len(ids))
-        ref = reference.logits_at(spec, flat, ids, rows, pad_to, rows_pad)
+        ref = arch.logits_at(spec, flat, ids, rows, pad_to, rows_pad)
         prog.append(reference.served_gaps(ref, toks))
         if control:
-            low = reference.logits_at(spec, flat, ids, rows, pad_to, rows_pad,
-                                      int8=True)
+            low = arch.logits_at(spec, flat, ids, rows, pad_to, rows_pad,
+                                 int8=True)
             ctrl.append(reference.served_gaps(ref, low.argmax(axis=-1)))
     out = {"program": _gap_stats(prog), "compared_requests": len(sample)}
     if control:
@@ -529,12 +534,12 @@ def prepare(cell: Cell, seed: int, peak_table: dict | None = None) -> Setup:
 
     enable_compile_cache()
     compiles = CompileLog()
-    spec = cell.spec
+    spec, arch = cell.spec, cell.arch
     peak = peak_table or counts.peaks(jax.devices()[0].device_kind)
-    cfg = model_cfg.register(spec)
+    cfg = model_cfg.register(spec, arch)
     args = launcher.parse_args(launcher_argv(spec, cell.mix))
-    flat = weights.make_flat(spec, seed)
-    params = weights.to_program(flat)
+    flat = weights.make_flat(arch, spec, seed)
+    params = arch.to_program(flat)
     weights.check_layout(params, cfg)
     warm_up(launcher, args, params, spec)
     jax.block_until_ready(flat)
@@ -545,8 +550,9 @@ def reseed(setup: Setup, seed: int) -> None:
     """New weights for ``seed`` in place of the old (freed first)."""
     setup.flat = setup.params = None
     gc.collect()
-    setup.flat = weights.make_flat(setup.cell.spec, seed)
-    setup.params = weights.to_program(setup.flat)
+    arch = setup.cell.arch
+    setup.flat = weights.make_flat(arch, setup.cell.spec, seed)
+    setup.params = arch.to_program(setup.flat)
     jax.block_until_ready(setup.flat)
 
 
@@ -596,7 +602,7 @@ def window(setup: Setup, seed: int, seconds: float, t_start: float,
              "peak_bytes": stats.get("peak_bytes_in_use"),
              "queue": eng.queue, "dispatching": eng.dispatching,
              "rates": list(launch.sched.rate_history)}
-    data = RunData(spec=spec, mix=mix, seconds=seconds,
+    data = RunData(spec=spec, arch=setup.cell.arch, mix=mix, seconds=seconds,
                    n_steps=e["steps_per_slot"], chunk=e["chunk_size"],
                    setup_s=t0 - t_start, compile_s=compile_s,
                    served=list(served.values()), starts=eng.starts,
@@ -620,7 +626,8 @@ def compared(setup: Setup, data: RunData, seed: int, control: bool = False) -> d
     spec, mix = setup.cell.spec, setup.cell.mix
     _, CL = traffic_gen.bucket(mix, spec["engine"]["page_size"])
     sample = sample_requests(data.done(), seed, setup.cell.limits["sample_tokens"])
-    return compare(spec, setup.flat, sample, CL, mix["output"]["max"], control)
+    return compare(setup.cell.arch, spec, setup.flat, sample, CL,
+                   mix["output"]["max"], control)
 
 
 def check(setup: Setup, data: RunData, seed: int, control: bool = False,

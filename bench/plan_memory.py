@@ -36,15 +36,16 @@ def step_programs(spec: dict, mix: dict, pages: int, sharding):
     from repro.models import transformer as T
     from repro.runtime import engine as E
 
-    cfg = model_cfg.register(spec)
+    arch = model_cfg.load_arch(spec["arch"])
+    cfg = model_cfg.register(spec, arch)
     e = spec["engine"]
     P, CL = traffic_gen.bucket(mix, e["page_size"])
     R, ps, C, n = e["max_active"], e["page_size"], e["chunk_size"], e["steps_per_slot"]
     MP = max(CL // ps, P // ps + 1)
     sds = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=sharding)
     on = lambda tree: jax.tree.map(lambda x: sds(x.shape, x.dtype), tree)
-    params = on(jax.eval_shape(lambda: weights.to_program(
-        weights.make_flat(spec, 0))))
+    params = on(jax.eval_shape(lambda: arch.to_program(
+        weights.make_flat(arch, spec, 0))))
     pools = on(jax.eval_shape(lambda: T.paged_pools_init(cfg, pages, ps)))
     state = M.PagedDecodeState(pools=pools, block_tables=sds((R, MP), jnp.int32),
                                pos=sds((R,), jnp.int32), last_tok=sds((R,), jnp.int32))

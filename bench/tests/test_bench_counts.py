@@ -1,5 +1,6 @@
-"""counts.py against hand-computed FLOPs and bytes for granite-3-2b and for
-granite-3-8b-20l (head_dim 128; a fixture until a cell uses it)."""
+"""The dense block's counts (arch/dense.py) and counts.py against
+hand-computed FLOPs and bytes for granite-3-2b and for granite-3-8b-20l
+(head_dim 128; a fixture until a cell uses it)."""
 import copy
 
 import benchpath
@@ -7,6 +8,8 @@ import pytest
 
 import counts
 import model_cfg
+
+dense = model_cfg.load_arch("dense")
 
 # per layer: D*H*hd + 2*D*KVH*hd + H*hd*D + 3*D*F, times 2 FLOPs, times L
 HAND = {
@@ -28,17 +31,17 @@ DIRS = {"granite-3-2b": model_cfg.CONFIG_DIR,
 @pytest.mark.parametrize("name", sorted(HAND))
 def test_counts_match_hand(name):
     spec, h = model_cfg.load(name, DIRS[name]), HAND[name]
-    assert counts.token_flops(spec) == h["token"]
-    assert counts.attn_flops(spec, 1000) == 1000 * h["attn_per_key"]
-    assert counts.logits_flops(spec) == h["logits"]
-    assert counts.weight_bytes(spec) == h["weights"]
-    assert counts.kv_token_bytes(spec) == h["kv"]
+    assert dense.token_flops(spec) == h["token"]
+    assert dense.attn_flops(spec, 1000) == 1000 * h["attn_per_key"]
+    assert dense.logits_flops(spec) == h["logits"]
+    assert dense.weight_bytes(spec) == h["weights"]
+    assert dense.kv_token_bytes(spec) == h["kv"]
     # two rows decoding at 100 and 300 keys
-    w = counts.decode_work(spec, [100, 300])
+    w = dense.decode_work(spec, [100, 300])
     assert w.flops == 2 * (h["token"] + h["logits"]) + 400 * h["attn_per_key"]
     assert w.bytes == h["weights"] + 400 * h["kv"]
     # a first chunk of 4 tokens that ends its prompt: keys 1+2+3+4
-    c = counts.chunk_work(spec, [(0, 4, True)])
+    c = dense.chunk_work(spec, [(0, 4, True)])
     assert c.flops == 4 * h["token"] + 10 * h["attn_per_key"] + h["logits"]
     assert c.bytes == h["weights"] + 4 * h["kv"]
 
@@ -51,8 +54,8 @@ def test_counts_ignore_cache_len_and_pool(name):
     other = copy.deepcopy(spec)
     other["engine"].update(num_pages=7, max_active=3, chunk_size=16, page_size=64)
     for s in (spec, other):
-        assert counts.decode_work(s, [17, 2000]) == counts.decode_work(spec, [17, 2000])
-        assert counts.chunk_work(s, [(32, 8, False)]) == counts.chunk_work(spec, [(32, 8, False)])
+        assert dense.decode_work(s, [17, 2000]) == dense.decode_work(spec, [17, 2000])
+        assert dense.chunk_work(s, [(32, 8, False)]) == dense.chunk_work(spec, [(32, 8, False)])
 
 
 def test_least_seconds_names_the_bound():
@@ -73,9 +76,9 @@ def test_slot_parts_follow_the_request_timeline():
         tokens = [0] * 40
         start_slot, first_token_slot, generated = 3, 5, [1] * 6
 
-    parts = counts.slot_parts(spec, [R()], n_steps=2, chunk=16)
+    parts = counts.slot_parts(dense, spec, [R()], n_steps=2, chunk=16)
     assert sorted(parts) == [3, 4, 5, 6, 7]
-    assert parts[3] == [counts.chunk_work(spec, [(0, 16, False)])]
-    assert parts[5] == [counts.chunk_work(spec, [(32, 8, True)]),
-                        counts.decode_work(spec, [41]), counts.decode_work(spec, [42])]
-    assert parts[7] == [counts.decode_work(spec, [45])]
+    assert parts[3] == [dense.chunk_work(spec, [(0, 16, False)])]
+    assert parts[5] == [dense.chunk_work(spec, [(32, 8, True)]),
+                        dense.decode_work(spec, [41]), dense.decode_work(spec, [42])]
+    assert parts[7] == [dense.decode_work(spec, [45])]

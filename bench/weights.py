@@ -1,11 +1,11 @@
 """Random weights for a configuration, made on the device from the seed.
 
-One jitted call builds the whole tree in the served type, in the layout the
-program's dense stack takes (``init_params``): the tied embedding, one
-layer-stacked segment of attention + gated MLP blocks, and the final norm.
-The benchmark owns these weights: the program is handed them, and the plain
-reference reads the same arrays, so the reference takes nothing the program
-has made. ``check_layout`` holds the layout to the program's own
+One jitted call builds the whole tree in the served type, in the layout and
+by the draw rules of the configuration's architecture module
+(``arch/<arch>.py``: ``shapes``, ``draw``). The benchmark owns these
+weights: the program is handed them (the module's ``to_program``), and the
+plain reference reads the same arrays, so the reference takes nothing the
+program has made. ``check_layout`` holds the layout to the program's own
 ``abstract_params`` so a change of layout fails here, not as wrong numbers.
 """
 from __future__ import annotations
@@ -15,80 +15,27 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 
-from model_cfg import head_dim
-
-
-def shapes(spec: dict) -> dict:
-    """{path: shape} of every weight, in the program's layout."""
-    L, D = spec["num_hidden_layers"], spec["hidden_size"]
-    H, KVH, hd = spec["num_attention_heads"], spec["num_key_value_heads"], head_dim(spec)
-    F, V = spec["intermediate_size"], spec["vocab_size"]
-    return {
-        "embed/tok": (V, D),
-        "ln1/scale": (L, D), "ln2/scale": (L, D), "ln_f/scale": (D,),
-        "attn/wq": (L, D, H, hd), "attn/wk": (L, D, KVH, hd),
-        "attn/wv": (L, D, KVH, hd), "attn/wo": (L, H, hd, D),
-        "mlp/w_gate": (L, D, F), "mlp/w_up": (L, D, F), "mlp/w_down": (L, F, D),
-    }
-
-
-# Standard deviation of each weight: fan-in scaling for the projections
-# (what the program's own init uses) and norm scales drawn around 1, so the
-# reference has to apply them. The tied embedding is drawn at 0.002: at the
-# program's init of 0.02, the input embedding times sqrt(hidden) outweighs
-# every block's output in the residual stream, so the model only copies its
-# input token, with a top-1 margin so wide that no arithmetic error could
-# ever change a greedy token and the comparison could not tell bfloat16 from
-# int8.
-EMBED_STD = 0.002
-
-
-def _scale(path: str, spec: dict) -> float:
-    D, F, hd = spec["hidden_size"], spec["intermediate_size"], head_dim(spec)
-    H = spec["num_attention_heads"]
-    return {"embed/tok": EMBED_STD, "attn/wo": (H * hd) ** -0.5,
-            "mlp/w_down": F ** -0.5}.get(path, D ** -0.5)
-
 
 @partial(jax.jit, static_argnums=(1, 2))
-def _make(key, spec_items: tuple, dtype: str):
-    spec = dict(spec_items)
+def _make(key, plan: tuple, dtype: str):
     out = {}
-    for i, (path, shape) in enumerate(sorted(shapes(spec).items())):
-        k = jax.random.fold_in(key, i)
-        if path.endswith("/scale"):
-            out[path] = 1.0 + 0.1 * jax.random.normal(k, shape, jnp.float32)
-        else:
-            w = _scale(path, spec) * jax.random.normal(k, shape, jnp.float32)
-            out[path] = w.astype(dtype)
+    for i, (path, shape, mean, std, f32) in enumerate(plan):
+        x = std * jax.random.normal(jax.random.fold_in(key, i), shape, jnp.float32)
+        if mean:
+            x = mean + x
+        out[path] = x if f32 else x.astype(dtype)
     return out
 
 
-_SPEC_KEYS = ("num_hidden_layers", "hidden_size", "num_attention_heads",
-              "num_key_value_heads", "intermediate_size", "vocab_size",
-              "head_dim")
-
-
-def make_flat(spec: dict, seed: int, dtype: str | None = None) -> dict:
-    """{path: array} on the default device; norm scales stay float32 (the
-    program's norms are float32), everything else ``dtype`` (default: the
-    configuration's served type)."""
-    items = tuple((k, spec.get(k)) for k in _SPEC_KEYS)
+def make_flat(arch, spec: dict, seed: int, dtype: str | None = None) -> dict:
+    """{path: array} on the default device: each weight drawn by its rule,
+    in sorted path order, from its own fold of the seed's key; the served
+    type is ``dtype`` (default: the configuration's)."""
+    plan = tuple((path, tuple(shape), *arch.draw(spec, path))
+                 for path, shape in sorted(arch.shapes(spec).items()))
     key = jax.random.fold_in(jax.random.key(int(seed) % (2 ** 32)),
                              int(seed) >> 32)
-    return _make(key, items, dtype or spec["torch_dtype"])
-
-
-def to_program(flat: dict) -> dict:
-    """The program's params tree over the same arrays (no copies)."""
-    seg = {
-        "ln1": {"scale": flat["ln1/scale"]},
-        "attn": {k: flat[f"attn/{k}"] for k in ("wq", "wk", "wv", "wo")},
-        "ln2": {"scale": flat["ln2/scale"]},
-        "mlp": {k: flat[f"mlp/{k}"] for k in ("w_gate", "w_up", "w_down")},
-    }
-    return {"embed": {"tok": flat["embed/tok"]}, "stack": [seg],
-            "ln_f": {"scale": flat["ln_f/scale"]}}
+    return _make(key, plan, dtype or spec["torch_dtype"])
 
 
 def check_layout(params: dict, cfg) -> None:
