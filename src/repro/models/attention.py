@@ -123,10 +123,11 @@ def kv_cache_init(batch: int, cache_len: int, cfg: ModelConfig) -> KVCache:
 
 
 class PagedKVPool(NamedTuple):
-    """Shared-pool paged KV storage for ONE layer (stacked on a leading
-    layer axis inside a segment, like every other cache leaf).
+    """Shared-pool paged KV storage for one layer, or for a segment's layers
+    stacked on a leading layer axis (as the engine holds it; the attention
+    functions address a stacked pool at a layer index).
 
-    k/v: (native_pages, page_size, KVH, hd). Rows are owned via
+    k/v: ([n,] native_pages, page_size, KVH, hd). Rows are owned via
     ``repro.cache.PageAllocator`` block tables; logical slot j of a request
     lives at (table[j // page_size], j % page_size) and holds absolute
     position j — paged caches never wrap, they grow by appending pages.
@@ -152,13 +153,16 @@ class PagedKVPool(NamedTuple):
     stage_k: Optional[jax.Array] = None   # (rows, stage_len, KVH, hd)
     stage_v: Optional[jax.Array] = None
 
+    # sizes read from the trailing axes: the same on one layer's pool and
+    # on the layer-stacked pool
+
     @property
     def native_pages(self) -> int:
-        return self.k.shape[0] if self.k is not None else 0
+        return self.k.shape[-4] if self.k is not None else 0
 
     @property
     def quant_pages(self) -> int:
-        return self.qk.shape[0] if self.qk is not None else 0
+        return self.qk.shape[-4] if self.qk is not None else 0
 
     @property
     def num_pages(self) -> int:
@@ -166,7 +170,7 @@ class PagedKVPool(NamedTuple):
 
     @property
     def page_size(self) -> int:
-        return (self.k if self.k is not None else self.qk).shape[1]
+        return (self.k if self.k is not None else self.qk).shape[-3]
 
 
 def paged_pool_init(num_pages: int, page_size: int, cfg: ModelConfig,
@@ -199,9 +203,17 @@ def paged_pool_init(num_pages: int, page_size: int, cfg: ModelConfig,
     return PagedKVPool(**kw)
 
 
-def _pool_read(pool: PagedKVPool, block_table: jax.Array, dtype):
+def _at(layer, *idx):
+    """Index of a pool leaf: ``idx`` on one layer's pool (``layer`` None),
+    ``(layer, *idx)`` on the layer-stacked pool, which is then read and
+    written where it lies, never sliced to a layer first."""
+    return idx if layer is None else (layer, *idx)
+
+
+def _pool_read(pool: PagedKVPool, block_table: jax.Array, dtype, layer=None):
     """Gather the logical K/V of every row through its block table ->
-    (B, MP*ps, KVH, hd). Quantized pages are dequantized to ``dtype``;
+    (B, MP*ps, KVH, hd), from layer ``layer`` of a stacked pool (``None``:
+    ``pool`` is one layer's). Quantized pages are dequantized to ``dtype``;
     native/cast pages are returned in their storage dtype when the pool has
     no quantized region (the callers' downstream casts are unchanged from
     the pre-quantization code, keeping those paths bit-identical)."""
@@ -209,15 +221,19 @@ def _pool_read(pool: PagedKVPool, block_table: jax.Array, dtype):
     ps = pool.page_size
     nn, nq = pool.native_pages, pool.quant_pages
     if pool.qk is None:
-        gather = jnp.clip(block_table, 0, nn - 1)
+        gather = _at(layer, jnp.clip(block_table, 0, nn - 1))
         kk = pool.k[gather]
         vv = pool.v[gather]
     else:
-        qidx = jnp.clip(block_table - nn, 0, nq - 1)
-        kk = dequantize_kv(pool.qk[qidx], pool.k_scale[qidx], dtype)
-        vv = dequantize_kv(pool.qv[qidx], pool.v_scale[qidx], dtype)
+        qpage = jnp.clip(block_table - nn, 0, nq - 1)
+        qidx = _at(layer, qpage)
+        # scales are gathered one page row at a time: with whole-page windows
+        # XLA relays the whole stacked scale array out anew in every layer
+        sidx = _at(layer, qpage[:, :, None], jnp.arange(ps))
+        kk = dequantize_kv(pool.qk[qidx], pool.k_scale[sidx], dtype)
+        vv = dequantize_kv(pool.qv[qidx], pool.v_scale[sidx], dtype)
         if pool.k is not None:   # mixed pool: per-page precision select
-            nidx = jnp.clip(block_table, 0, nn - 1)
+            nidx = _at(layer, jnp.clip(block_table, 0, nn - 1))
             is_native = ((block_table >= 0) & (block_table < nn))[:, :, None, None, None]
             kk = jnp.where(is_native, pool.k[nidx].astype(dtype), kk)
             vv = jnp.where(is_native, pool.v[nidx].astype(dtype), vv)
@@ -226,27 +242,29 @@ def _pool_read(pool: PagedKVPool, block_table: jax.Array, dtype):
 
 
 def _pool_write(pool: PagedKVPool, phys: jax.Array, off: jax.Array,
-                k: jax.Array, v: jax.Array, prec: KVPrecision) -> PagedKVPool:
-    """Scatter native-dtype K/V rows into the pool at (phys, off). ``phys``
-    must already carry the drop sentinel (num_pages) for invalid entries;
-    each region's scatter drops writes aimed at the other region."""
+                k: jax.Array, v: jax.Array, prec: KVPrecision,
+                layer=None) -> PagedKVPool:
+    """Scatter native-dtype K/V rows into the pool at (phys, off), in layer
+    ``layer`` of a stacked pool (``None``: ``pool`` is one layer's).
+    ``phys`` must already carry the drop sentinel (num_pages) for invalid
+    entries; each region's scatter drops writes aimed at the other region."""
     nn, nq = pool.native_pages, pool.quant_pages
     new = pool
     if pool.k is not None:
-        nidx = jnp.minimum(phys, nn)              # quant region / pads -> drop
+        nidx = _at(layer, jnp.minimum(phys, nn), off)   # quant region / pads -> drop
         new = new._replace(
-            k=new.k.at[nidx, off].set(k.astype(new.k.dtype), mode="drop"),
-            v=new.v.at[nidx, off].set(v.astype(new.v.dtype), mode="drop"),
+            k=new.k.at[nidx].set(k.astype(new.k.dtype), mode="drop"),
+            v=new.v.at[nidx].set(v.astype(new.v.dtype), mode="drop"),
         )
     if pool.qk is not None:
-        qidx = jnp.where(phys >= nn, phys - nn, nq)   # native region -> drop
+        qidx = _at(layer, jnp.where(phys >= nn, phys - nn, nq), off)   # native region -> drop
         qk_, ks_ = quantize_kv(k, prec)
         qv_, vs_ = quantize_kv(v, prec)
         new = new._replace(
-            qk=new.qk.at[qidx, off].set(qk_, mode="drop"),
-            qv=new.qv.at[qidx, off].set(qv_, mode="drop"),
-            k_scale=new.k_scale.at[qidx, off].set(ks_, mode="drop"),
-            v_scale=new.v_scale.at[qidx, off].set(vs_, mode="drop"),
+            qk=new.qk.at[qidx].set(qk_, mode="drop"),
+            qv=new.qv.at[qidx].set(qv_, mode="drop"),
+            k_scale=new.k_scale.at[qidx].set(ks_, mode="drop"),
+            v_scale=new.v_scale.at[qidx].set(vs_, mode="drop"),
         )
     return new
 
@@ -737,6 +755,9 @@ def attn_chunk_paged(
     valid: jax.Array,        # (B,)
     cfg: ModelConfig,
     base: Optional[jax.Array] = None,   # (B,) prefix-cache hit tokens per row
+    *,
+    layer: Optional[jax.Array] = None,      # () layer of a stacked pool
+    stage_row: Optional[jax.Array] = None,  # (B,) staging row of each row
 ) -> tuple[jax.Array, PagedKVPool]:
     """``attn_chunk`` over the shared page pool: the chunk's K/V rows land in
     block-table pages (logical slot j at (table[j // ps], j % ps)), then the
@@ -753,6 +774,10 @@ def attn_chunk_paged(
     already-rounded tenant) while positions >= base come from staging —
     chunk N re-reads chunk N-1 exactly, restoring the one-shot-prefill
     equivalence contract for quantized/cast storage.
+
+    ``layer`` addresses layer ``layer`` of a layer-stacked pool in place
+    (``None``: ``pool`` is one layer's); ``stage_row`` names the staging
+    row of each of the B rows (default: row b stages in row b).
     """
     B, C, D = x.shape
     N, ps = pool.num_pages, pool.page_size
@@ -772,14 +797,7 @@ def attn_chunk_paged(
     phys = jnp.take_along_axis(block_table, lp, axis=1)   # (B, C)
     phys = jnp.where(keep & (phys >= 0), phys, N)         # N = out of range -> drop
     off = pos % ps
-    if pool.qk is None:
-        cdt = pool.k.dtype
-        pool = pool._replace(
-            k=pool.k.at[phys, off].set(k.astype(cdt), mode="drop"),
-            v=pool.v.at[phys, off].set(v.astype(cdt), mode="drop"),
-        )
-    else:
-        pool = _pool_write(pool, phys, off, k, v, kv_precision_of(cfg))
+    pool = _pool_write(pool, phys, off, k, v, kv_precision_of(cfg), layer)
 
     H, KVH, hd = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim_
     G = H // KVH
@@ -789,29 +807,31 @@ def attn_chunk_paged(
     if pool.stage_k is not None:
         # native staging for the in-flight prompt: pool pages serve only the
         # prefix-cache hit [0, base); staging serves [base, qpos]
-        SL = pool.stage_k.shape[1]
-        b_idx = jnp.arange(B)[:, None]
+        SL = pool.stage_k.shape[-3]
+        srow = jnp.arange(B) if stage_row is None else stage_row
         spos = jnp.where(keep, jnp.minimum(pos, SL), SL)  # SL = drop
+        sidx = _at(layer, srow[:, None], spos)
         pool = pool._replace(
-            stage_k=pool.stage_k.at[b_idx, spos].set(
+            stage_k=pool.stage_k.at[sidx].set(
                 k.astype(pool.stage_k.dtype), mode="drop"),
-            stage_v=pool.stage_v.at[b_idx, spos].set(
+            stage_v=pool.stage_v.at[sidx].set(
                 v.astype(pool.stage_v.dtype), mode="drop"),
         )
         if base is None:
             base = jnp.zeros((B,), jnp.int32)
-        kk, vv = _pool_read(pool, block_table, q.dtype)
+        kk, vv = _pool_read(pool, block_table, q.dtype, layer)
         kk = kk.astype(q.dtype)
         vv = vv.astype(q.dtype)
         ok_pool = allocated & (j < base[:, None, None]) & (j <= pos[:, :, None])
         js = jnp.arange(SL)[None, None, :]
         ok_stage = (js >= base[:, None, None]) & (js <= pos[:, :, None])
-        kk = jnp.concatenate([kk, pool.stage_k.astype(q.dtype)], axis=1)
-        vv = jnp.concatenate([vv, pool.stage_v.astype(q.dtype)], axis=1)
+        sk, sv = pool.stage_k[_at(layer, srow)], pool.stage_v[_at(layer, srow)]
+        kk = jnp.concatenate([kk, sk.astype(q.dtype)], axis=1)
+        vv = jnp.concatenate([vv, sv.astype(q.dtype)], axis=1)
         ok = jnp.concatenate([ok_pool, jnp.broadcast_to(
             ok_stage, (B, C, SL))], axis=-1)
     else:
-        kk, vv = _pool_read(pool, block_table, q.dtype)
+        kk, vv = _pool_read(pool, block_table, q.dtype, layer)
         ok = allocated & (j <= pos[:, :, None])           # (B, C, MP*ps)
     s = jnp.einsum(
         "bqkgh,bskh->bkgqs", qg, kk.astype(q.dtype),
@@ -835,6 +855,8 @@ def attn_decode_paged(
     block_table: jax.Array,  # (B, MP) int32 physical page ids; -1 = unallocated
     pos: jax.Array,          # (B,) absolute position of the new token
     cfg: ModelConfig,
+    *,
+    layer: Optional[jax.Array] = None,   # () layer of a stacked pool
 ) -> tuple[jax.Array, PagedKVPool]:
     """One decode step against the paged pool: rope at pos, write the new
     row into the block-table page, attend over the gathered logical cache.
@@ -845,6 +867,8 @@ def attn_decode_paged(
     the validity mask (j <= pos on allocated pages) equals the dense
     slot_pos mask. Rows of inactive requests carry an all(-1) block table —
     their write is dropped and their output is discarded by the engine.
+    ``layer`` addresses layer ``layer`` of a layer-stacked pool in place
+    (``None``: ``pool`` is one layer's).
     """
     B, D = x.shape
     N, ps = pool.num_pages, pool.page_size
@@ -862,16 +886,8 @@ def attn_decode_paged(
     phys = jnp.take_along_axis(block_table, lp[:, None], axis=1)[:, 0]
     phys = jnp.where(phys < 0, N, phys)               # N = out of range -> drop
     off = (pos % ps).astype(jnp.int32)
-    if pool.qk is None:
-        cdt = pool.k.dtype
-        pool = pool._replace(
-            k=pool.k.at[phys, off].set(k.astype(cdt), mode="drop"),
-            v=pool.v.at[phys, off].set(v.astype(cdt), mode="drop"),
-        )
-    else:
-        pool = _pool_write(pool, phys, off, k, v, kv_precision_of(cfg))
-
-    kk, vv = _pool_read(pool, block_table, q.dtype)
+    pool = _pool_write(pool, phys, off, k, v, kv_precision_of(cfg), layer)
+    kk, vv = _pool_read(pool, block_table, q.dtype, layer)
 
     H, KVH, hd = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim_
     G = H // KVH

@@ -476,7 +476,12 @@ def chunk_hidden_paged(stack, h, pools, block_table, pos0, valid,
     the whole stack, like ``decode_hidden_paged``). ``base`` (B,) is the
     per-row prefix-cache hit length — under a lossy precision it splits
     attention between pool pages [0, base) and the native staging buffer,
-    whose row r is the engine row's."""
+    whose row r is the engine row's.
+
+    Each segment's stacked pool rides in the layer scan's carry and in the
+    row loop's, and every leaf (pages, scales, staging) is written and read
+    at (layer, row or page, offset) in place: no layer's slab is sliced out
+    or written back (DESIGN.md §6)."""
     segs = plan_segments(cfg, "decoder")
     rows, n_rows = _chunk_rows(valid)
     if base is None:
@@ -486,29 +491,22 @@ def chunk_hidden_paged(stack, h, pools, block_table, pos0, valid,
         if seg.kind != "attn":
             raise ValueError(f"chunked prefill is not supported for {seg.kind!r} blocks")
 
-        def body(hh, lp, params=params):
-            l, pool_l = lp
+        def body(carry, l, params=params):
+            hh, pool = carry
 
-            def attend(x, pool_l, r, p):
-                stage = (pool_l.stage_k, pool_l.stage_v)
-                if pool_l.stage_k is not None:
-                    sk, sv = _take_row(stage, r)
-                    pool_l = pool_l._replace(stage_k=sk, stage_v=sv)
-                a, pool_l = A.attn_chunk_paged(
-                    p["attn"], x, pool_l, block_table[r][None], pos0[r][None],
-                    valid[r][None], cfg, base=base[r][None],
+            def attend(x, pool, r, p):
+                return A.attn_chunk_paged(
+                    p["attn"], x, pool, block_table[r][None], pos0[r][None],
+                    valid[r][None], cfg, base=base[r][None], layer=l,
+                    stage_row=r[None],
                 )
-                if pool_l.stage_k is not None:
-                    sk, sv = _put_row(stage, (pool_l.stage_k, pool_l.stage_v), r)
-                    pool_l = pool_l._replace(stage_k=sk, stage_v=sv)
-                return a, pool_l
 
-            hh, pool_l = _chunk_layer_rows(params, l, hh, pool_l, rows, n_rows,
-                                           attend, cfg)
-            return constrain(hh), pool_l
+            hh, pool = _chunk_layer_rows(params, l, hh, pool, rows, n_rows,
+                                         attend, cfg)
+            return (constrain(hh), pool), None
 
-        h, seg_pool = jax.lax.scan(body, h, (jnp.arange(seg.n), pool))
-        new_pools.append(seg_pool)
+        (h, pool), _ = jax.lax.scan(body, (h, pool), jnp.arange(seg.n))
+        new_pools.append(pool)
     return h, new_pools
 
 
@@ -554,26 +552,31 @@ def decode_hidden_paged(stack, h, pools, block_table, pos, cfg: ModelConfig):
     Mirrors ``decode_hidden`` (same scan structure, same residual/FFN op
     order) with ``attn_decode_paged`` in place of ``attn_decode``, so the
     two paths are bit-identical on shared-length workloads. The block table
-    is shared by every layer; each layer owns its (num_pages, ...) pool row.
+    is shared by every layer. Each segment's stacked pool rides in the
+    scan's carry beside ``h``; layer l writes and gathers its K/V at
+    (l, page, offset) in place, so no step slices, rewrites or copies the
+    whole pool (DESIGN.md §6).
     """
     segs = plan_segments(cfg, "decoder")
     new_pools = []
     for seg, params, pool in zip(segs, stack, pools, strict=True):
         assert seg.kind in ("attn", "attn_moe"), seg.kind
 
-        def body(hh, pp):
-            p, pool_l = pp
+        def body(carry, pl):
+            hh, pool = carry
+            p, l = pl
             with jax.named_scope("attn"):
-                a, pool_l = A.attn_decode_paged(
-                    p["attn"], rmsnorm(p["ln1"], hh, cfg.norm_eps), pool_l,
-                    block_table, pos, cfg,
+                a, pool = A.attn_decode_paged(
+                    p["attn"], rmsnorm(p["ln1"], hh, cfg.norm_eps), pool,
+                    block_table, pos, cfg, layer=l,
                 )
             with jax.named_scope("ffn"):
                 hh, _ = _ffn(p, hh + a, cfg)
-            return hh, pool_l
+            return (hh, pool), None
 
-        h, seg_pool = jax.lax.scan(body, h, (params, pool))
-        new_pools.append(seg_pool)
+        (h, pool), _ = jax.lax.scan(body, (h, pool),
+                                    (params, jnp.arange(seg.n)))
+        new_pools.append(pool)
     return h, new_pools
 
 
